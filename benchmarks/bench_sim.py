@@ -260,7 +260,7 @@ def main(smoke: bool = False):
                 1e6 / ws["per_w"][top]["minutes_per_sec"],
                 f"w{top}_mps={ws['per_w'][top]['minutes_per_sec']:,.0f}", ws)
 
-    # ---- fused plant kernel vs oracle (interpret mode on CPU) ----------
+    # ---- fused plant kernel vs oracle (interpret mode off the TPU) -----
     B, S, T = (8, 30, 14) if smoke else (64, 30, 14)
     st = dict(
         ready=rng.gamma(2.0, 2.0, B), queue=rng.gamma(1.0, 25.0, B),
@@ -271,27 +271,31 @@ def main(smoke: bool = False):
         st["ready"], pipeline, st["queue"], st["wait_sum"],
         st["util_ema"], st["cooldown"], pipeline.sum(1), st["ready"] * 30))
     tk = common.timeit(lambda: jax.block_until_ready(
-        kops.plant_tick_block(*args, n_ticks=T, interpret=True)),
+        kops.plant_tick_block(*args, n_ticks=T)),
         warmup=1, iters=iters)
     tr = common.timeit(lambda: jax.block_until_ready(
         kref.plant_block_ref(*args, n_ticks=T)), warmup=1, iters=iters)
-    kp = {"lanes": B, "n_ticks": T, "interpret_mode": True,
-          "note": "CPU interpret mode validates the kernel; the TPU "
-                  "number is the real speed claim",
+    interpret = jax.default_backend() != "tpu"
+    kp = {"lanes": B, "n_ticks": T, "interpret_mode": interpret,
+          "note": "interpret mode (off the TPU) validates the kernel; "
+                  "only a TPU number is a speed",
           "kernel_us": tk, "ref_us": tr, "ref_over_kernel": tr / tk}
-    common.emit("sim_kernel", tk, f"interpret_ref_ratio={tr/tk:.2f}", kp)
+    common.emit("sim_kernel", tk, f"ref_ratio={tr/tk:.2f}", kp)
 
     # ---- fused-decide episode kernel trajectory, per policy ------------
     # ci=30 keeps the unrolled-tick jaxpr small enough that the interpret
     # kernel compiles in seconds per policy (the TPU path is agnostic).
+    # Only the policies whose decide lowers on TPU (`tpu_kernel`).
     dk_cfg = SimConfig(control_interval_sec=30)
-    dk_names = ("hpa",) if smoke else tuple(registry.available())
+    dk_names = tuple(n for n in registry.available()
+                     if registry.get_controller(n, dk_cfg).tpu_kernel)
+    dk_names = dk_names[:1] if smoke else dk_names
     dk_M = 24 if smoke else 60
     dk_rates = rates[:, :dk_M]
-    dk = {"workloads": W, "minutes": dk_M, "interpret_mode": True,
+    dk = {"workloads": W, "minutes": dk_M, "interpret_mode": interpret,
           "control_interval_sec": dk_cfg.control_interval_sec,
-          "note": "CPU interpret mode validates the fused-decide episode "
-                  "kernel; the TPU number is the real speed claim",
+          "note": "interpret mode (off the TPU) validates the fused-"
+                  "decide episode kernel; only a TPU number is a speed",
           "policies": {}}
     for name in dk_names:
         ctrl = registry.get_controller(name, dk_cfg)
@@ -307,37 +311,12 @@ def main(smoke: bool = False):
         dk["policies"][name] = {
             "minutes_per_sec": {k: W * dk_M / v for k, v in t.items()},
             "fused_over_block_head": t["block_head"] / t["fused_decide"]}
-    lead = "aapa" if "aapa" in dk["policies"] else dk_names[0]
+    lead = dk_names[0]
     lead_mps = dk["policies"][lead]["minutes_per_sec"]["fused_decide"]
     common.emit(
         "sim_fused_decide", 1e6 / lead_mps,
-        f"{lead}_interpret_fused_vs_blocked="
+        f"{lead}_fused_vs_blocked="
         f"{dk['policies'][lead]['fused_over_block_head']:.3f}x", dk)
-
-    # ---- GBDT node-table kernel lanes/sec ------------------------------
-    from repro.core import gbdt
-    Ng = 256 if smoke else 4096
-    Fg = 38
-    Xs = rng.normal(size=(512, Fg)).astype(np.float32)
-    ys = rng.integers(0, 4, 512).astype(np.int32)
-    params = gbdt.fit(Xs, ys,
-                      gbdt.GBDTConfig(n_rounds=8 if smoke else 20))
-    Xq = jnp.asarray(rng.normal(size=(Ng, Fg)).astype(np.float32))
-    host_tables = jax.jit(gbdt.predict_logits)
-    tgk = common.timeit(lambda: jax.block_until_ready(
-        kops.gbdt_logits(params, Xq, interpret=True)),
-        warmup=1, iters=iters)
-    tgr = common.timeit(lambda: jax.block_until_ready(
-        host_tables(params, Xq)), warmup=1, iters=iters)
-    gk = {"rows": Ng, "features": Fg, "rounds": int(params.feat.shape[0]),
-          "depth": int(params.depth), "interpret_mode": True,
-          "kernel_us": tgk, "host_table_us": tgr,
-          "kernel_lanes_per_sec": Ng / (tgk / 1e6),
-          "note": "CPU interpret mode validates the node-table kernel "
-                  "(bit-exact vs the host table path); the TPU number "
-                  "is the real speed claim"}
-    common.emit("sim_gbdt_kernel", tgk,
-                f"lanes_per_sec={Ng / (tgk / 1e6):,.0f}", gk)
 
 
 if __name__ == "__main__":

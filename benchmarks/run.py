@@ -43,6 +43,8 @@ def main() -> None:
                             bench_roofline, bench_sim, bench_tuning,
                             bench_uncertainty)
     from benchmarks import common
+    from repro import compile_cache
+    compile_cache.enable()
     benches = [
         ("sim", bench_sim),
         ("fleet", bench_fleet),

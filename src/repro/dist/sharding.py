@@ -6,9 +6,9 @@ constrain activations with logical names:
 
     h = shd.constrain(h, ("dp", "mp", None))
 
-and a launcher activates a mesh once:
+and a launcher builds its mesh with `make_mesh` and activates it once:
 
-    rules = shd.set_mesh(make_production_mesh())
+    rules = shd.set_mesh(shd.make_mesh((4,), ("data",)))
 
 "dp" resolves to every data-parallel axis present (("pod", "data") on the
 multi-pod mesh, ("data",) on a single pod), "mp" to the "model" axis. With
@@ -30,7 +30,7 @@ import math
 from typing import Any, Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 _LOG = logging.getLogger(__name__)
 _WARNED: set[tuple] = set()      # (logical, axis_size, dim) already logged
@@ -105,6 +105,15 @@ class MeshRules:
 
 
 _ACTIVE: MeshRules | None = None
+
+
+def make_mesh(shape, axis_names, *, devices=None) -> Mesh:
+    """The repository's one mesh constructor: `jax.make_mesh` with every
+    axis Auto. `jax.make_mesh` builds Explicit axes by default, and
+    `constrain` (`with_sharding_constraint`) accepts only Auto axes."""
+    return jax.make_mesh(tuple(shape), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def set_mesh(mesh: Mesh | None) -> MeshRules | None:

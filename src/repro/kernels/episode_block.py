@@ -11,9 +11,10 @@ arithmetic never runs on-chip. This kernel fuses the entire episode:
   every controller-state leaf live in VMEM **scratch that persists
   across grid steps** — the whole episode advances without touching HBM
   except for the streams below;
-* the rate trace streams in one minute-column per grid step and the 12
+* the rate trace streams in as (tile, 128-minute) blocks and the 12
   per-minute aggregates stream out the same way (BlockSpec index maps
-  give the automatic double-buffered DMA pipeline);
+  give the automatic double-buffered DMA pipeline); each grid step
+  reads and writes its own minute's column under a lane mask;
 * at each control-period head the controller update runs *inside* the
   kernel: ``controller.decide`` vmapped over the lane tile (hpa / kpa /
   predictive are a handful of vector ops; AAPA's archetype strategy
@@ -43,11 +44,13 @@ lane counts). Compiled-program parity is ulp-tight, not bitwise — the
 two paths are different XLA programs, so FMA contraction may differ
 (see the `_flow_tick` stability note for why the drift stays ~1e-6).
 
-Known real-TPU lowering gap (interpret mode is unaffected): an AAPA
-reclassification stride that fires in-episode pulls
-``jnp.fft.rfft`` (10 of the 38 features) into the kernel body, which
-Mosaic does not lower today; the `requires_tpu` lane pins the policies
-without that dependence and documents the rest.
+On a TPU the body must lower through Mosaic, which takes elementwise
+math, selects and static slices, but no gather, scatter, sort or FFT.
+hpa and kpa qualify and declare it (`Controller.tpu_kernel`, compiled
+for a v5e in tests/test_tpu_compile.py). predictive (Holt-Winters
+seasonal slot by index), aapa and hybrid (sorted and rFFT features,
+GBDT table gathers) do not, so the auto rule sends them to the XLA
+scan; interpret mode runs every policy.
 """
 from __future__ import annotations
 
@@ -68,6 +71,9 @@ from repro.sim.cluster import (MinuteOut, SimConfig, _acc_fold, _acc_init,
 #: the decide fused here is what reads/writes it)
 PLANT_COLS = ("ready", "queue", "wait_sum", "util_ema", "cooldown",
               "pipe_sum", "last_dir")
+
+#: minutes per streamed rate/output block: one full 128-lane vreg row
+COL_BLOCK = 128
 
 
 def _make_minute_body(controller: Controller, cfg: SimConfig, tile_b: int,
@@ -182,9 +188,15 @@ def _hoist(fun, example_args):
 def _episode_kernel(rate_ref, *refs, minute_conv, const_shapes,
                     n_leaves):
     """One grid step = one minute for one lane tile. refs order: hoisted
-    closure constants, 12 MinuteOut column outputs, then scratch (plant
+    closure constants, 12 MinuteOut outputs, then scratch (plant
     (TILE_B, 7) in PLANT_COLS order, pipeline (TILE_B, S), history ring
-    (TILE_B, H), one buffer per controller-state leaf)."""
+    (TILE_B, H), one buffer per controller-state leaf).
+
+    The rate input and the outputs move in (TILE_B, COL_BLOCK) blocks:
+    minute m is column m % COL_BLOCK of block m // COL_BLOCK, read and
+    written with a lane mask. The TPU compiler accepts neither a
+    one-column block nor a dynamic one-column lane slice, and the
+    output block stays resident until its 128 minutes are written."""
     n_consts = len(const_shapes)
     const_refs = refs[:n_consts]
     out_refs = refs[n_consts:n_consts + 12]
@@ -194,9 +206,11 @@ def _episode_kernel(rate_ref, *refs, minute_conv, const_shapes,
 
     consts = [r[:].reshape(s) for r, s in zip(const_refs, const_shapes)]
     leaves = tuple(r[:] for r in ctrl_refs)
+    col = (jax.lax.broadcasted_iota(jnp.int32, rate_ref.shape, 1)
+           == m % COL_BLOCK)
+    rate = jnp.sum(jnp.where(col, rate_ref[...], 0.0), axis=1)
     plant, pipe, hist, leaves, outs = minute_conv(
-        (plant_ref[:], pipe_ref[:], hist_ref[:], leaves,
-         rate_ref[:, 0], m), consts)
+        (plant_ref[:], pipe_ref[:], hist_ref[:], leaves, rate, m), consts)
 
     plant_ref[:] = plant
     pipe_ref[:] = pipe
@@ -204,7 +218,7 @@ def _episode_kernel(rate_ref, *refs, minute_conv, const_shapes,
     for r, leaf in zip(ctrl_refs, leaves):
         r[:] = leaf
     for r, v in zip(out_refs, outs):
-        r[:, 0] = v
+        r[...] = jnp.where(col, v[:, None], r[...])
 
 
 def episode_minutes(controller: Controller, cfg: SimConfig,
@@ -229,7 +243,8 @@ def episode_minutes(controller: Controller, cfg: SimConfig,
 
     n_tiles = max((B + tile_b - 1) // tile_b, 1)
     pad_b = n_tiles * tile_b
-    rp = jnp.zeros((pad_b, M), jnp.float32).at[:B].set(rates)
+    pad_m = -(-M // COL_BLOCK) * COL_BLOCK
+    rp = jnp.zeros((pad_b, pad_m), jnp.float32).at[:B, :M].set(rates)
 
     # hoist every array the controller closes over (Table III, GBDT node
     # tables, forecaster seasonals, init buffers) into explicit inputs
@@ -252,7 +267,8 @@ def episode_minutes(controller: Controller, cfg: SimConfig,
         pl.BlockSpec(a.shape, functools.partial(
             lambda nd, i, m: (0,) * nd, a.ndim)) for a in const_in]
 
-    col = pl.BlockSpec((tile_b, 1), lambda i, m: (i, m))
+    col = pl.BlockSpec((tile_b, COL_BLOCK),
+                       lambda i, m: (i, m // COL_BLOCK))
     scratch = [pltpu.VMEM((tile_b, 7), jnp.float32),
                pltpu.VMEM((tile_b, S), jnp.float32),
                pltpu.VMEM((tile_b, H), jnp.float32)]
@@ -266,8 +282,8 @@ def episode_minutes(controller: Controller, cfg: SimConfig,
         grid=(n_tiles, M),
         in_specs=[col] + const_specs,
         out_specs=[col] * 12,
-        out_shape=[jax.ShapeDtypeStruct((pad_b, M), jnp.float32)] * 12,
+        out_shape=[jax.ShapeDtypeStruct((pad_b, pad_m), jnp.float32)] * 12,
         scratch_shapes=scratch,
         interpret=interpret,
     )(rp, *const_in)
-    return MinuteOut(*(o[:B] for o in outs))
+    return MinuteOut(*(o[:B, :M] for o in outs))

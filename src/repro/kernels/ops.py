@@ -1,8 +1,10 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels run in interpret mode for validation;
-on TPU set ``interpret=False`` (the default flips automatically based on
-the backend).
+`interpret=None` (the default) compiles the kernel with Mosaic on a TPU
+backend and runs the Pallas interpreter everywhere else — the CPU
+correctness vehicle. ``tests/test_tpu_compile.py`` compiles every kernel
+a TPU run can take for a described TPU v5e; `gbdt_logits` does not lower
+there (its node-table gathers) and runs only under the interpreter.
 """
 from __future__ import annotations
 
@@ -39,7 +41,7 @@ def extract_features_fused(windows: jax.Array, *, tile_n: int = 256,
 
 
 def holt_winters(y: jax.Array, *, period: int = 60, alpha: float = 0.1,
-                 beta: float = 0.01, gamma: float = 0.3, tile_b: int = 8,
+                 beta: float = 0.01, gamma: float = 0.3, tile_b: int = 128,
                  interpret: bool | None = None) -> jax.Array:
     """[B, T] -> one-step-ahead Holt-Winters forecasts [B, T]."""
     if interpret is None:

@@ -6,8 +6,8 @@ dynamics — startup-pipeline pop, fluid queue, response model, utilization
 EMA, limiter cooldown decay. Those plant ticks are the hot loop of every
 paper table, and they are elementwise over lanes (one lane = one
 simulated workload), so the TPU mapping is: one grid step per tile of
-``TILE_B`` lanes held in VMEM sublanes, the tick loop inside the kernel
-(``lax.fori_loop``), the startup pipeline kept as a ``(TILE_B,
+``TILE_B`` lanes held in VMEM sublanes, the tick loop unrolled inside
+the kernel, the startup pipeline kept as a ``(TILE_B,
 startup_sec)`` VMEM tile shifted one slot per tick — the whole control
 period advances without touching HBM.
 
@@ -40,7 +40,12 @@ def _kernel(state_ref, pipe_ref, st_out_ref, pipe_out_ref, served_ref,
     tile_b, S = pipe_ref.shape
     arrivals = state_ref[:, 6:7]                     # (TILE_B, 1)
 
-    def body(t, carry):
+    carry = (state_ref[:, 0:1], pipe_ref[:, :], state_ref[:, 1:2],
+             state_ref[:, 2:3], state_ref[:, 3:4], state_ref[:, 4:5],
+             state_ref[:, 5:6])
+    # unrolled: every per-tick store lands at a static lane offset (the
+    # TPU compiler refuses a dynamic one-column lane slice)
+    for t in range(n_ticks):
         ready, pipe, queue, wait, util_ema, cool, ps = carry
         # pods finishing startup: pop slot 0, shift the pipeline
         popped = pipe[:, 0:1]
@@ -70,20 +75,15 @@ def _kernel(state_ref, pipe_ref, st_out_ref, pipe_out_ref, served_ref,
         util_ema = util_ema + (util - util_ema) / metric_tau_sec
         cool = jnp.maximum(cool - 1.0, 0.0)
 
-        served_ref[:, pl.dslice(t, 1)] = served
-        viol_ref[:, pl.dslice(t, 1)] = viol
-        cold_ref[:, pl.dslice(t, 1)] = cold
-        total_ref[:, pl.dslice(t, 1)] = ready + ps
-        resp_ref[:, pl.dslice(t, 1)] = resp
-        util_ref[:, pl.dslice(t, 1)] = util
-        ready_ref[:, pl.dslice(t, 1)] = ready
-        return ready, pipe, new_queue, wait, util_ema, cool, ps
-
-    carry0 = (state_ref[:, 0:1], pipe_ref[:, :], state_ref[:, 1:2],
-              state_ref[:, 2:3], state_ref[:, 3:4], state_ref[:, 4:5],
-              state_ref[:, 5:6])
-    ready, pipe, queue, wait, util_ema, cool, ps = jax.lax.fori_loop(
-        0, n_ticks, body, carry0)
+        served_ref[:, t:t + 1] = served
+        viol_ref[:, t:t + 1] = viol
+        cold_ref[:, t:t + 1] = cold
+        total_ref[:, t:t + 1] = ready + ps
+        resp_ref[:, t:t + 1] = resp
+        util_ref[:, t:t + 1] = util
+        ready_ref[:, t:t + 1] = ready
+        carry = (ready, pipe, new_queue, wait, util_ema, cool, ps)
+    ready, pipe, queue, wait, util_ema, cool, ps = carry
     st_out_ref[:, :] = jnp.concatenate(
         [ready, queue, wait, util_ema, cool, ps, arrivals], axis=1)
     pipe_out_ref[:, :] = pipe

@@ -47,7 +47,7 @@ def episode_block_ref(rates, controller, cfg):
     episode_block module docstring)."""
     from repro.sim.cluster import simulate
     return jax.vmap(lambda r: simulate(r, controller, cfg,
-                                       plant_kernel=False))(rates)
+                                       decide_kernel=False))(rates)
 
 
 def gbdt_logits_ref(params, X):

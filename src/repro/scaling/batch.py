@@ -316,7 +316,6 @@ def make_batch_minute_step(controllers: Sequence[Controller],
 
 def make_batch_simulator(controllers: Sequence[Controller],
                          cfg: SimConfig = SimConfig(), *,
-                         plant_kernel: bool | None = None,
                          decide_kernel: bool | None = None,
                          shard: bool = True, w_chunk: int | None = None,
                          donate: bool = False, telemetry: bool = False,
@@ -324,13 +323,10 @@ def make_batch_simulator(controllers: Sequence[Controller],
     """jit: rates [W, M] -> MinuteOut [P, W, M]. One compile, one
     dispatch: a single blocked scan over fused P x W plant lanes with
     exactly P (not P^2) decide evaluations per control step.
-    (`plant_kernel` is accepted for signature parity with
-    `make_simulator`; the fused-lane batch always uses the vector plant
-    path, which IS the kernel's oracle.)
 
-    `decide_kernel` (auto on TPU, same dispatch as
-    ``cluster.make_simulator``) instead runs one fused-decide episode
-    kernel per controller over the W lanes — every policy's whole
+    `decide_kernel` (auto on TPU when every controller declares
+    `tpu_kernel`, ``cluster.use_decide_kernel``) instead runs one
+    fused-decide episode kernel per controller over the W lanes — every policy's whole
     episode on-chip (``repro.kernels.episode_block``), stacked back to
     [P, W, M], still one compile. The off path is the unchanged fused
     P x W scan. Incompatible with `telemetry` (decisions stay on-chip).
@@ -347,18 +343,17 @@ def make_batch_simulator(controllers: Sequence[Controller],
     ``repro.evals.fleet`` is for: pass `trace_lanes` on its `FleetSpec`
     to stream sampled-lane traces per chunk.
     """
-    del plant_kernel
     if telemetry and w_chunk is not None:
         raise ValueError(
             "telemetry does not compose with w_chunk here; for chunked "
             "capture use repro.evals.fleet with trace_lanes "
             "(FleetSpec(..., trace_lanes=K) samples K lanes per chunk)")
     from repro.sim.cluster import (_reject_decide_kernel_telemetry,
-                                   _use_decide_kernel)
-    use_dk = _use_decide_kernel(decide_kernel)
+                                   use_decide_kernel)
+    ctrls = list(controllers)
+    use_dk = use_decide_kernel(decide_kernel, *ctrls)
     if use_dk and telemetry:
         _reject_decide_kernel_telemetry()
-    ctrls = list(controllers)
     step = make_batch_minute_step(ctrls, cfg, shard=shard,
                                   telemetry=telemetry,
                                   trace_lanes=trace_lanes)

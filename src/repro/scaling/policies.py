@@ -96,7 +96,7 @@ def hpa_controller(cfg, *, target: float = 0.70,
         return (HPAState(buf, desired), desired,
                 jnp.float32(cooldown_min * 60.0))
 
-    return Controller("hpa", init, on_minute, decide)
+    return Controller("hpa", init, on_minute, decide, tpu_kernel=True)
 
 
 # --------------------------------------------------- Generic Predictive ----
@@ -148,6 +148,9 @@ def predictive_controller(cfg, *, target: float = 0.70,
                           confidence=_nan(), archetype=_nan(),
                           guard_floor=_nan())
 
+    # tpu_kernel stays False: the Holt-Winters state reads and writes its
+    # seasonal slot by index, a gather/scatter the TPU kernel compiler
+    # does not lower
     return Controller("predictive", init, on_minute, decide, explain)
 
 
@@ -252,6 +255,9 @@ def aapa_controller(
                           archetype=state.arch.astype(jnp.float32),
                           guard_floor=_nan())
 
+    # tpu_kernel stays False: reclassification sorts and runs an rFFT
+    # (core.features) and the GBDT walks its node tables by gather, none
+    # of which the TPU kernel compiler lowers (ROADMAP: DFT on the MXU)
     return Controller("aapa", init, on_minute, decide, explain)
 
 
@@ -321,7 +327,7 @@ def kpa_controller(cfg, *, target_concurrency: float | None = None,
         return (KPAState(stable, panic, panic_left, panic_max), desired,
                 jnp.float32(cooldown_min * 60.0))
 
-    return Controller("kpa", init, on_minute, decide)
+    return Controller("kpa", init, on_minute, decide, tpu_kernel=True)
 
 
 # ---------------------------------------------------------------- hybrid ----

@@ -174,7 +174,12 @@ def _apply_scaling(ready, pipeline, pipe_sum, act):
     """Turn a ScaleAction into pipeline/ready updates: starts enter the
     pipeline tail; removals cancel starting pods first (proportional
     rescale), then ready pods. Shape-agnostic like `_pop_pipeline`."""
-    pipeline = pipeline.at[..., -1].add(act.add)
+    # a static-slot add written as a concatenate, not `.at[..., -1].add`:
+    # the same float op, without the scatter the TPU kernel compiler
+    # (kernels/episode_block.py) cannot lower
+    pipeline = jnp.concatenate(
+        [pipeline[..., :-1], pipeline[..., -1:] + act.add[..., None]],
+        axis=-1)
     pipe_sum = pipe_sum + act.add
     n_start = pipe_sum
     from_pipe = jnp.minimum(act.remove, n_start)
@@ -402,10 +407,10 @@ def advance_plant(cfg: SimConfig, ready, pipeline, pipe_sum, queue,
 
 def _plant_block(cfg: SimConfig, state: SimState, acc,
                  arrivals: jax.Array, n_ticks: int, use_kernel: bool):
-    """`n_ticks` plant-only ticks folded into the minute accumulator.
-    CPU/ref: `advance_plant` (the kernel's parity oracle semantics).
-    TPU: one fused `plant_tick_block` kernel call advancing the whole
-    block in VMEM."""
+    """`n_ticks` plant-only ticks folded into the minute accumulator:
+    `advance_plant` (the kernel's parity oracle semantics), or with
+    `use_kernel` (`plant_kernel=True`) one fused `plant_tick_block`
+    kernel call advancing the whole block in VMEM."""
     if not use_kernel:
         (ready, pipeline, pipe_sum, queue, wait_sum, util_ema,
          cool), acc = advance_plant(
@@ -588,24 +593,19 @@ def initial_state(controller: Controller,
         ctrl_state=controller.init())
 
 
-def _use_plant_kernel(explicit: bool | None) -> bool:
-    """Dual dispatch shared with `window_features` / `holt_winters`: the
-    fused Pallas block kernel on TPU, the blocked path (its oracle)
-    elsewhere."""
-    if explicit is None:
-        return jax.default_backend() == "tpu"
-    return explicit
-
-
-def _use_decide_kernel(explicit: bool | None) -> bool:
+def use_decide_kernel(explicit: bool | None, *controllers) -> bool:
     """Dispatch for the fused-decide episode kernel
-    (``repro.kernels.episode_block``): same scheme as
-    `_use_plant_kernel` — the kernel on TPU, the blocked scan below (its
-    oracle) elsewhere. The off path is the unmodified blocked scan, so
+    (``repro.kernels.episode_block``). `explicit` True/False forces it;
+    None (auto) takes the kernel on TPU only when every controller
+    declares `tpu_kernel` (its decide lowers there — hpa and kpa), and
+    the blocked scan below (the kernel's oracle) everywhere else. So on
+    TPU predictive / aapa / hybrid run the compiled XLA scan, and on CPU
+    every policy does. The off path is the unmodified blocked scan, so
     `decide_kernel=False` is bit-exact with not passing the flag at all
     on CPU (pinned in tests/test_decide_kernel.py)."""
     if explicit is None:
-        return jax.default_backend() == "tpu"
+        return (jax.default_backend() == "tpu"
+                and all(c.tpu_kernel for c in controllers))
     return explicit
 
 
@@ -628,19 +628,20 @@ minute_step_reference = _minute_reference
 
 def simulate(rates_per_min: jax.Array, controller: Controller,
              cfg: SimConfig = SimConfig(), *,
-             plant_kernel: bool | None = None,
+             plant_kernel: bool = False,
              decide_kernel: bool | None = None,
              telemetry: bool = False) -> MinuteOut:
     """Simulate one workload. rates_per_min [M] -> MinuteOut of [M] arrays.
 
     Control-period-blocked: `decide` runs once per control interval
     (bit-exact with `simulate_reference`, which evaluates it every tick).
-    `plant_kernel=None` auto-selects the fused Pallas plant kernel on TPU
-    for the decision-free ticks; `decide_kernel=None` auto-selects the
-    *whole-episode* fused kernel (``repro.kernels.episode_block``) on
-    TPU — plant ticks and `decide` both on-chip, this blocked scan as
-    its dispatch oracle. `decide_kernel` subsumes `plant_kernel` when
-    on.
+    `plant_kernel=True` advances the decision-free ticks with the fused
+    Pallas plant kernel (off by default: no chip measurement has shown
+    it winning); `decide_kernel=None` auto-selects the *whole-episode*
+    fused kernel (``repro.kernels.episode_block``) on TPU for a
+    controller that declares `tpu_kernel` (see `use_decide_kernel`) —
+    plant ticks and `decide` both on-chip, this blocked scan as its
+    dispatch oracle. `decide_kernel` subsumes `plant_kernel` when on.
 
     `telemetry=True` (static) additionally captures the in-scan decision
     trace and returns ``(MinuteOut, ControlTrace)`` with decisions
@@ -648,16 +649,15 @@ def simulate(rates_per_min: jax.Array, controller: Controller,
     the default path compiles to the identical pre-telemetry program.
     Incompatible with `decide_kernel` (decisions stay on-chip there).
     """
-    if _use_decide_kernel(decide_kernel):
+    if use_decide_kernel(decide_kernel, controller):
         if telemetry:
             _reject_decide_kernel_telemetry()
         from repro.kernels import ops
         out = ops.episode_block(rates_per_min.astype(jnp.float32)[None],
                                 controller, cfg)
         return jax.tree.map(lambda a: a[0], out)
-    use_kernel = _use_plant_kernel(plant_kernel)
     (state, _), out = jax.lax.scan(
-        partial(_minute_blocked, cfg, controller, use_kernel=use_kernel,
+        partial(_minute_blocked, cfg, controller, use_kernel=plant_kernel,
                 telemetry=telemetry),
         (initial_state(controller, cfg), jnp.int32(0)),
         rates_per_min.astype(jnp.float32))
@@ -677,7 +677,7 @@ def simulate_reference(rates_per_min: jax.Array, controller: Controller,
 
 
 def make_simulator(controller: Controller, cfg: SimConfig = SimConfig(), *,
-                   plant_kernel: bool | None = None,
+                   plant_kernel: bool = False,
                    decide_kernel: bool | None = None,
                    w_chunk: int | None = None, donate: bool = False,
                    telemetry: bool = False):
@@ -692,13 +692,14 @@ def make_simulator(controller: Controller, cfg: SimConfig = SimConfig(), *,
     returns ``(MinuteOut [W, M], ControlTrace)`` with decisions leaves
     [W, M, H] and minutes leaves [W, M].
 
-    `decide_kernel` (auto on TPU, like `plant_kernel`) routes whole
-    episodes through the fused-decide Pallas kernel — the W lanes ARE
+    `decide_kernel` (auto on TPU for a `tpu_kernel` controller, see
+    `use_decide_kernel`) routes whole episodes through the fused-decide
+    Pallas kernel — the W lanes ARE
     the kernel's lane tiles, so the vmap disappears and the episode is
     one kernel launch per w-chunk inside the same single compile
     (`_cache_size()` stays 1, pinned in tests/test_decide_kernel.py).
     Incompatible with `telemetry` (decisions stay on-chip)."""
-    if _use_decide_kernel(decide_kernel):
+    if use_decide_kernel(decide_kernel, controller):
         if telemetry:
             _reject_decide_kernel_telemetry()
         from repro.kernels import ops

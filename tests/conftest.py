@@ -13,22 +13,3 @@ def pytest_configure(config):
         "markers",
         "slow: heavyweight model/train/system tests, run in the nightly "
         "full-suite CI job (tier-1 deselects them with -m 'not slow')")
-    config.addinivalue_line(
-        "markers",
-        "requires_tpu: compiled-mode (interpret=False) kernel parity "
-        "pins; auto-skipped unless jax.default_backend() == 'tpu'")
-
-
-def pytest_collection_modifyitems(config, items):
-    import pytest
-    tpu_items = [it for it in items
-                 if it.get_closest_marker("requires_tpu") is not None]
-    if not tpu_items:
-        return
-    import jax
-    if jax.default_backend() == "tpu":
-        return
-    skip = pytest.mark.skip(
-        reason="requires a TPU backend (interpret=False kernel path)")
-    for it in tpu_items:
-        it.add_marker(skip)

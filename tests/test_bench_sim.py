@@ -27,15 +27,15 @@ def _check_doc(doc, *, smoke):
     assert not doc["failed"]
     names = [r["name"] for r in doc["records"]]
     assert names == ["sim_blocked", "sim_batch", "sim_workloads",
-                     "sim_kernel", "sim_fused_decide", "sim_gbdt_kernel"]
+                     "sim_kernel", "sim_fused_decide"]
     for r in doc["records"]:
         assert set(r) == {"name", "us_per_call", "derived"}
         assert r["us_per_call"] > 0
     blocked = doc["records"][0]
     assert blocked["derived"].startswith("aapa_blocked_speedup=")
     fused = doc["records"][4]
-    assert "_interpret_fused_vs_blocked=" in fused["derived"]
-    assert doc["records"][5]["derived"].startswith("lanes_per_sec=")
+    assert fused["derived"].startswith("hpa_fused_vs_blocked=")
+    assert doc["records"][3]["derived"].startswith("ref_ratio=")
 
 
 @pytest.mark.slow

@@ -15,10 +15,11 @@ Three guarantees, mirrored from the `plant_kernel=` flag:
   instead of silently returning empty traces.
 
 The interpret-mode fused-vs-oracle parity itself is pinned per policy in
-test_kernel_smoke.py; the `requires_tpu` test at the bottom re-pins it
-with `interpret=False` on real hardware.
+test_kernel_smoke.py; the `tpu_backend`-gated test at the bottom re-pins
+it with `interpret=False` on real hardware.
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -111,14 +112,23 @@ def test_telemetry_w_chunk_error_names_fleet_front_door():
     assert "trace_lanes" in msg and "evals.fleet" in msg
 
 
-@pytest.mark.requires_tpu
-def test_fused_compiled_parity_on_tpu():
-    """interpret=False (Mosaic-compiled) fused episode vs the CPU blocked
-    scan, for the non-fft policies (AAPA's rfft reclassify features are
-    not Mosaic-lowerable yet; see the episode_block docstring)."""
+@pytest.fixture
+def tpu_backend():
+    """Skip unless this process runs on a TPU. Asked inside the test, so
+    collecting the file never starts a backend."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("requires a TPU backend (interpret=False kernel path)")
+
+
+def test_fused_compiled_parity_on_tpu(tpu_backend):
+    """interpret=False (Mosaic-compiled) fused episode vs the blocked
+    scan, for every policy the auto rule sends to the kernel on TPU
+    (`tpu_kernel`; see the episode_block docstring for the rest)."""
     from repro.kernels import ops, ref
     rates = _rates()
-    for name in ("hpa", "kpa", "predictive"):
+    names = [n for n in registry.available() if _ctrl(n).tpu_kernel]
+    assert names
+    for name in names:
         ctrl = registry.get_controller(name, CFG)
         got = ops.episode_block(rates, ctrl, CFG, interpret=False)
         want = ref.episode_block_ref(rates, ctrl, CFG)

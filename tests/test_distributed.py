@@ -44,7 +44,7 @@ def test_sharded_train_step_matches_single_device():
         p1, o1, m1 = jax.jit(ts)(params, opt, batch)
 
         # 4x2 mesh
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = shd.make_mesh((4, 2), ("data", "model"))
         shd.set_mesh(mesh)
         in_sh = (shd.param_shardings(params),
                  type(opt)(None, shd.param_shardings(opt.master),
@@ -79,7 +79,7 @@ def test_moe_ep_matches_scatter_path():
 
         ref, _ = moe_block_scatter(p, x, cfg)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = shd.make_mesh((2, 4), ("data", "model"))
         shd.set_mesh(mesh)
         with mesh:
             out, _ = jax.jit(lambda p, x: moe_block(p, x, cfg))(p, x)
@@ -95,16 +95,17 @@ def test_elastic_restore_onto_different_mesh(tmp_path):
     code = textwrap.dedent("""
         import json, numpy as np, jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.dist import sharding as shd
         from repro.train import checkpoint as ckpt
 
         tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
-        mesh1 = jax.make_mesh((8,), ("data",))
+        mesh1 = shd.make_mesh((8,), ("data",))
         sh1 = {"w": NamedSharding(mesh1, P("data", None))}
         placed = jax.device_put(tree, sh1)
         ckpt.save("%s", 1, placed)
 
         # restore onto a *different* topology (2x2 submesh, model axis)
-        mesh2 = jax.make_mesh((2, 2), ("data", "model"))
+        mesh2 = shd.make_mesh((2, 2), ("data", "model"))
         sh2 = {"w": NamedSharding(mesh2, P("model", "data"))}
         restored, step = ckpt.restore("%s", jax.eval_shape(lambda: tree),
                                       shardings=sh2)

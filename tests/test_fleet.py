@@ -264,7 +264,7 @@ def test_sharded_matrix_matches_unsharded_8dev():
         rei1 = score(pool1)
 
         # 8-way dp mesh; input placed with lane_sharding (W axis = 2)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = shd.make_mesh((8,), ("data",))
         rules = shd.set_mesh(mesh)
         sh = shd.lane_sharding(rates.shape, w_axis=2, strict=True)
         assert sh.spec == P(None, None, "data", None), sh.spec
@@ -339,7 +339,7 @@ def test_sharded_fleet_matches_unsharded_8dev():
                           w_chunk=16, minutes=40, seed=0)
         res1 = fleet.run_fleet(spec)
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = shd.make_mesh((8,), ("data",))
         shd.set_mesh(mesh)
         with mesh:
             res2 = fleet.run_fleet(spec)

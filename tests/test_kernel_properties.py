@@ -131,16 +131,18 @@ def _gbdt_params(seed, rounds, depth):
        st.integers(min_value=2, max_value=6),
        st.integers(min_value=2, max_value=3))
 def test_gbdt_tables_parity_any_shape(n, tile_n, rounds, depth):
-    """Node-table kernel is BIT-exact vs the host table path for
-    arbitrary row counts (including non-multiple-of-tile), tile sizes,
-    and tree geometries."""
+    """Node-table kernel == the host table path for arbitrary row counts
+    (including non-multiple-of-tile), tile sizes, and tree geometries.
+    Ulp-tight, not bitwise: the kernel and the host path are two
+    compiled programs, and XLA may order the per-class leaf reduction
+    differently in each (see test_gbdt_tables_small_shape_parity)."""
     params = _gbdt_params(rounds * 10 + depth, rounds, depth)
     rng = np.random.default_rng(n * 7919 + tile_n)
     X = jnp.asarray(rng.normal(size=(n, 9)).astype(np.float32))
     got = np.asarray(ops.gbdt_logits(params, X, tile_n=tile_n,
                                      interpret=True))
     want = np.asarray(ref.gbdt_logits_ref(params, X))
-    np.testing.assert_array_equal(got, want)
+    np.testing.assert_allclose(got, want, rtol=2e-6, atol=1e-6)
 
 
 @settings(max_examples=4, deadline=None)

@@ -48,9 +48,13 @@ def test_window_features_small_shape_parity():
     np.testing.assert_allclose(got, want, rtol=5e-4, atol=5e-4)
 
 
-def test_holt_winters_small_shape_parity():
+@pytest.mark.parametrize("t", (120, 1100))
+def test_holt_winters_small_shape_parity(t):
+    """t=1100 spans three time blocks of the kernel grid, so the level /
+    trend / season state carried in VMEM scratch across blocks is
+    exercised."""
     rng = np.random.default_rng(7)
-    y = rng.gamma(2.0, 5.0, size=(4, 120)).astype(np.float32)
+    y = rng.gamma(2.0, 5.0, size=(4, t)).astype(np.float32)
     got = np.asarray(ops.holt_winters(jnp.asarray(y), period=24,
                                       interpret=True))
     want = np.asarray(ref.holt_winters_ref(jnp.asarray(y), period=24))
@@ -110,13 +114,15 @@ def test_episode_block_policy_parity(policy):
 
 
 def test_gbdt_tables_small_shape_parity():
-    """Node-table kernel is BIT-exact vs the host table path (identical
-    traversal over the identical layout), on a row count that does not
-    divide the tile."""
+    """Node-table kernel == the host table path (identical traversal
+    over the identical layout), on a row count that does not divide the
+    tile. Ulp-tight, not bitwise: the kernel and the host path are two
+    compiled programs, and XLA may order the per-class leaf reduction
+    differently in each (a few f32 ulp at logit magnitudes ~0.1-1)."""
     params = _tiny_gbdt()
     rng = np.random.default_rng(23)
     X = jnp.asarray(rng.normal(size=(37, 38)).astype(np.float32))
     got = np.asarray(ops.gbdt_logits(params, X, tile_n=16,
                                      interpret=True))
     want = np.asarray(ref.gbdt_logits_ref(params, X))
-    np.testing.assert_array_equal(got, want)
+    np.testing.assert_allclose(got, want, rtol=2e-6, atol=1e-6)
