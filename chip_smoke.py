@@ -290,9 +290,12 @@ class CompileClock:
 
 
 @contextlib.contextmanager
-def reported(name: str, clock: CompileClock, info: dict):
+def reported(name: str, clock: CompileClock):
+    """Times the block and prints one JSON line with what the block put
+    into the yielded dict."""
     t0, c0 = time.perf_counter(), clock.seconds
-    yield
+    info = {}
+    yield info
     line = {"phase": name, "wall_s": time.perf_counter() - t0,
             "compile_s": clock.seconds - c0, **info}
     print(json.dumps(line, default=float), flush=True)
@@ -322,26 +325,20 @@ def main(argv=None) -> int:
                       "compile_cache": cache_dir}), flush=True)
 
     if args.chips == 4:
-        info = {}
-        with reported("fleet_dp", clock, info):
+        with reported("fleet_dp", clock) as info:
             info.update(phase_fleet_dp(fleet_spec(), args.chips))
     else:
-        info = {}
-        with reported("dataset", clock, info):
+        with reported("dataset", clock) as info:
             trained, built, more = phase_dataset()
             info.update(more)
         classify = trained.make_classify()
-        info = {}
-        with reported("calibration", clock, info):
+        with reported("calibration", clock) as info:
             info.update(phase_calibration(built.series)[1])
-        info = {}
-        with reported("fleet", clock, info):
+        with reported("fleet", clock) as info:
             info.update(phase_fleet(fleet_spec(), classify))
-        info = {}
-        with reported("matrix", clock, info):
+        with reported("matrix", clock) as info:
             info.update(phase_matrix(classify, "chip-smoke-aapaset-ci"))
-        info = {}
-        with reported("episodes", clock, info):
+        with reported("episodes", clock) as info:
             info.update(phase_episodes(classify))
 
     cache = pathlib.Path(cache_dir)

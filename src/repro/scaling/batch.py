@@ -326,9 +326,9 @@ def make_batch_simulator(controllers: Sequence[Controller],
 
     `decide_kernel` (auto on TPU when every controller declares
     `tpu_kernel`, ``cluster.use_decide_kernel``) instead runs one
-    fused-decide episode kernel per controller over the W lanes — every policy's whole
-    episode on-chip (``repro.kernels.episode_block``), stacked back to
-    [P, W, M], still one compile. The off path is the unchanged fused
+    fused-decide episode kernel per controller over the W lanes — every
+    policy's whole episode on-chip (``repro.kernels.episode_block``),
+    stacked back to [P, W, M], still one compile. The off path is the unchanged fused
     P x W scan. Incompatible with `telemetry` (decisions stay on-chip).
 
     `w_chunk` scans over chunks of the workload axis inside the same

@@ -107,8 +107,7 @@ def test_phase_fleet_dp_one_device():
 
 def test_phase_lines_are_json(capsys):
     clock = cs.CompileClock()
-    info = {}
-    with cs.reported("demo", clock, info):
+    with cs.reported("demo", clock) as info:
         info["x"] = 1
     line = json.loads(capsys.readouterr().out.strip())
     assert line["phase"] == "demo" and line["x"] == 1
