@@ -12,15 +12,13 @@ Four measurements, all in simulated workload-minutes per wall-second:
 * `sim_batch`    — the O(P) per-controller-lane batch vs the seed's
   stacked O(P^2) design (every lane evaluates all P decides) at P = 1..5.
 * `sim_workloads`— blocked-scan scaling in the workload axis.
-* `sim_kernel`   — the fused Pallas plant kernel vs its jnp oracle on a
-  lane tile. On CPU the kernel runs in INTERPRET mode (a correctness
-  vehicle, not a speed claim — the TPU number is the real one).
-* `sim_fused_decide` — the kernel-path trajectory per policy: the
-  whole-episode fused-decide kernel (`decide` inside the Pallas plant
-  kernel) vs the block-head-return blocked scan vs the tick-level
-  reference. Interpret mode on CPU, same caveat as `sim_kernel`.
-* `sim_gbdt_kernel` — the vectorized GBDT node-table kernel lanes/sec
-  vs the host table path on a small synthetic fit.
+* `sim_fused_decide` — the kernel-path trajectory for the policies the
+  `decide_kernel` auto rule sends to the episode kernel on TPU (hpa and
+  kpa, `Controller.tpu_kernel`; hpa leads the record): the whole-episode
+  fused-decide kernel vs the block-head blocked scan vs the tick-level
+  reference. The kernel follows the backend: compiled by Mosaic on a
+  TPU, the Pallas interpreter elsewhere (a correctness vehicle, not a
+  speed claim).
 
 `python -m benchmarks.run sim --json .` writes the records to
 BENCH_sim.json (stable schema) so perf regressions diff across PRs.
@@ -35,7 +33,6 @@ import jax.numpy as jnp
 
 from benchmarks import common
 from repro.kernels import ops as kops
-from repro.kernels import ref as kref
 from repro.scaling import batch, registry
 from repro.scaling.api import Obs, apply_decision
 from repro.sim.cluster import (SimConfig, initial_state, simulate,
@@ -260,28 +257,6 @@ def main(smoke: bool = False):
                 1e6 / ws["per_w"][top]["minutes_per_sec"],
                 f"w{top}_mps={ws['per_w'][top]['minutes_per_sec']:,.0f}", ws)
 
-    # ---- fused plant kernel vs oracle (interpret mode off the TPU) -----
-    B, S, T = (8, 30, 14) if smoke else (64, 30, 14)
-    st = dict(
-        ready=rng.gamma(2.0, 2.0, B), queue=rng.gamma(1.0, 25.0, B),
-        wait_sum=rng.gamma(1.0, 5.0, B), util_ema=rng.random(B),
-        cooldown=rng.uniform(0, 20, B))
-    pipeline = rng.gamma(1.0, 0.6, (B, S)).astype(np.float32)
-    args = tuple(jnp.asarray(v, jnp.float32) for v in (
-        st["ready"], pipeline, st["queue"], st["wait_sum"],
-        st["util_ema"], st["cooldown"], pipeline.sum(1), st["ready"] * 30))
-    tk = common.timeit(lambda: jax.block_until_ready(
-        kops.plant_tick_block(*args, n_ticks=T)),
-        warmup=1, iters=iters)
-    tr = common.timeit(lambda: jax.block_until_ready(
-        kref.plant_block_ref(*args, n_ticks=T)), warmup=1, iters=iters)
-    interpret = jax.default_backend() != "tpu"
-    kp = {"lanes": B, "n_ticks": T, "interpret_mode": interpret,
-          "note": "interpret mode (off the TPU) validates the kernel; "
-                  "only a TPU number is a speed",
-          "kernel_us": tk, "ref_us": tr, "ref_over_kernel": tr / tk}
-    common.emit("sim_kernel", tk, f"ref_ratio={tr/tk:.2f}", kp)
-
     # ---- fused-decide episode kernel trajectory, per policy ------------
     # ci=30 keeps the unrolled-tick jaxpr small enough that the interpret
     # kernel compiles in seconds per policy (the TPU path is agnostic).
@@ -292,6 +267,7 @@ def main(smoke: bool = False):
     dk_names = dk_names[:1] if smoke else dk_names
     dk_M = 24 if smoke else 60
     dk_rates = rates[:, :dk_M]
+    interpret = jax.default_backend() != "tpu"
     dk = {"workloads": W, "minutes": dk_M, "interpret_mode": interpret,
           "control_interval_sec": dk_cfg.control_interval_sec,
           "note": "interpret mode (off the TPU) validates the fused-"

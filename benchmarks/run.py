@@ -10,10 +10,18 @@
 # in jax.profiler.trace (one trace subdir per bench, viewable in
 # TensorBoard/Perfetto) so a fleet-scale regression is attributed to a
 # dispatch, not guessed at.
+#
+# JAX's persistent compilation cache is placed by repro.compile_cache
+# (JAX_COMPILATION_CACHE_DIR if set, else .jax_cache/ in the checkout), so
+# a number that includes compile time (bench_aapaset's cold build, a
+# streaming run's first chunk) depends on whether an earlier run filled
+# that cache; each BENCH_<bench>.json records who placed the cache and
+# how many entries it held when the run started.
 from __future__ import annotations
 
 import inspect
 import json
+import os
 import pathlib
 import sys
 import time
@@ -23,11 +31,13 @@ BENCH_SCHEMA_VERSION = 1
 
 
 def _write_json(out_dir: pathlib.Path, bench: str, records: list,
-                elapsed_s: float, failed: bool, smoke: bool) -> None:
+                elapsed_s: float, failed: bool, smoke: bool,
+                compile_cache: dict) -> None:
     out_dir.mkdir(parents=True, exist_ok=True)
     doc = {"schema": BENCH_SCHEMA_VERSION, "bench": bench,
            "smoke": smoke, "elapsed_s": round(elapsed_s, 3),
-           "failed": failed, "records": records}
+           "failed": failed, "compile_cache": compile_cache,
+           "records": records}
     path = out_dir / f"BENCH_{bench}.json"
     with open(path, "w") as f:
         json.dump(doc, f, indent=1, sort_keys=True, default=float)
@@ -44,7 +54,11 @@ def main() -> None:
                             bench_uncertainty)
     from benchmarks import common
     from repro import compile_cache
-    compile_cache.enable()
+    cache_dir = pathlib.Path(compile_cache.enable())
+    cache = {"placed_by": ("env" if os.environ.get(compile_cache.ENV)
+                           else "checkout"),
+             "entries_at_start": (len(list(cache_dir.iterdir()))
+                                  if cache_dir.is_dir() else 0)}
     benches = [
         ("sim", bench_sim),
         ("fleet", bench_fleet),
@@ -78,6 +92,8 @@ def main() -> None:
     argv = [a for a in argv if a != "--smoke"]
     only = argv[0] if argv else None
 
+    print(f"# compile cache: {cache_dir} "
+          f"({cache['entries_at_start']} entries at start)")
     print("name,us_per_call,derived")
     failures = 0
     for name, mod in benches:
@@ -113,7 +129,7 @@ def main() -> None:
             # a bench without a smoke variant ran its full workload even
             # under --smoke; label its records accordingly
             _write_json(json_dir, name, records, time.time() - t0, failed,
-                        bool(kwargs.get("smoke", False)))
+                        bool(kwargs.get("smoke", False)), cache)
         print(f"# [{name}] {time.time()-t0:.0f}s", flush=True)
     if failures:
         sys.exit(1)

@@ -14,8 +14,8 @@ Phases (one process, no CPU fallback):
    to ``experiments/``);
 3. conformal calibration of Holt-Winters (the ``holt_winters`` kernel);
 4. fleet — ``evals.fleet.run_fleet`` of hpa + aapa over a 10^5-lane
-   ``burst_storm`` fleet in one dispatch, then streaming its first
-   chunks;
+   ``burst_storm`` fleet in one dispatch, then its first chunks both
+   streamed and in one dispatch;
 5. the Table IV matrix of the quickstart (five policies,
    ``archetype_mix``, 1440 minutes, 16 workloads);
 6. single episodes (``make_simulator`` / ``simulate``) per registry
@@ -32,7 +32,6 @@ from __future__ import annotations
 import argparse
 import contextlib
 import dataclasses
-import itertools
 import json
 import pathlib
 import sys
@@ -93,6 +92,23 @@ def check_metrics(what: str, dev, host, *, rtol: float = METRIC_RTOL):
             worst = max(worst, err / max(abs(h), 1e-9))
     if bad:
         raise AssertionError(f"{what}: " + "; ".join(bad))
+    return worst
+
+
+def check_same_fleet(what: str, got, want) -> float:
+    """Two FleetResults over the same lanes: pooled metrics and REI
+    agree at DP_RTOL (the repository's rule for two compiled programs).
+    Returns the worst relative difference of the pooled metrics."""
+    worst = 0.0
+    for field in EM.EpisodeMetrics._fields:
+        a = np.asarray(getattr(want.pooled, field))
+        b = np.asarray(getattr(got.pooled, field))
+        np.testing.assert_allclose(b, a, rtol=DP_RTOL,
+                                   err_msg=f"{what}: {field}")
+        worst = max(worst, float(np.max(np.abs(a - b)
+                                        / np.maximum(np.abs(a), 1e-9))))
+    np.testing.assert_allclose(got.rei.rei, want.rei.rei, rtol=DP_RTOL,
+                               err_msg=f"{what}: REI")
     return worst
 
 
@@ -167,30 +183,37 @@ def fleet_spec(*, n_workloads: int = 100_000, w_chunk: int = 1000,
 
 
 def phase_fleet(spec: fleet.FleetSpec, classify, *, stream_chunks: int = 10):
-    """The fleet in one dispatch (warm), then streaming its first
-    chunks; chunk 0 alone is checked against the NumPy oracle."""
+    """The fleet in one dispatch (warm), then its first `stream_chunks`
+    chunks twice: through the streaming fold, and in one dispatch. The
+    two must agree at DP_RTOL (the chunk scan's carry against the
+    streaming fold), and the one-dispatch run must match the NumPy
+    oracle over per-lane runs of the same lanes."""
     res = fleet.run_fleet(spec, classify=classify, warmup=True)
-    stream = fleet.run_fleet(
-        spec, classify=classify, stream=True,
-        chunks=itertools.islice(fleet.rate_chunks(spec), stream_chunks))
-    chunk0 = dataclasses.replace(spec, n_workloads=spec.w_chunk)
-    got = fleet.run_fleet(chunk0, classify=classify).pooled
-    want = oracle_pooled(fleet.controllers(spec, classify),
-                         spec.sim_config(), fleet.chunk_rates(spec, 0))
-    worst = max(check_metrics(f"fleet chunk 0 {p}",
-                              jax.tree.map(lambda a: a[i], got), want[i])
-                for i, p in enumerate(spec.policies))
     for i, p in enumerate(spec.policies):
         v = float(res.pooled.slo_violation_rate[i])
         if not np.isfinite(v):
             raise AssertionError(f"fleet {p}: violation rate {v}")
+    head = dataclasses.replace(spec,
+                               n_workloads=stream_chunks * spec.w_chunk)
+    stream = fleet.run_fleet(head, classify=classify, stream=True)
+    once = fleet.run_fleet(head, classify=classify)
+    stream_err = check_same_fleet("fleet streamed vs one dispatch",
+                                  stream, once)
+    want = oracle_pooled(fleet.controllers(spec, classify),
+                         spec.sim_config(),
+                         fleet.build_rates(head).reshape(-1, spec.minutes))
+    worst = max(check_metrics(f"fleet first {stream_chunks} chunks {p}",
+                              jax.tree.map(lambda a: a[i], once.pooled),
+                              want[i])
+                for i, p in enumerate(spec.policies))
     return {"workloads": res.meta["workloads"],
             "lane_minutes_per_sec": res.meta["lane_minutes_per_sec"],
             "dispatch_s": res.meta["wall_s"], "build_s": res.meta["build_s"],
             "stream_workloads": stream.meta["workloads"],
             "stream_lane_minutes_per_sec":
                 stream.meta["lane_minutes_per_sec"],
-            "chunk0_worst_rel_err": worst, "path": "xla_scan"}
+            "stream_vs_dispatch_worst_rel_err": stream_err,
+            "oracle_worst_rel_err": worst, "path": "xla_scan"}
 
 
 def phase_matrix(classify, classifier_id: str, *, n_workloads: int = 16,
@@ -261,14 +284,8 @@ def phase_fleet_dp(spec: fleet.FleetSpec, n_devices: int):
             many = fleet.run_fleet(spec, warmup=True)
     finally:
         shd.set_mesh(None)
-    worst = 0.0
-    for field in EM.EpisodeMetrics._fields:
-        a = np.asarray(getattr(one.pooled, field))
-        b = np.asarray(getattr(many.pooled, field))
-        np.testing.assert_allclose(b, a, rtol=DP_RTOL, err_msg=field)
-        worst = max(worst, float(np.max(np.abs(a - b)
-                                        / np.maximum(np.abs(a), 1e-9))))
-    np.testing.assert_allclose(many.rei.rei, one.rei.rei, rtol=DP_RTOL)
+    worst = check_same_fleet(f"fleet on {n_devices} devices vs one",
+                             many, one)
     return {"devices": n_devices, "mesh": many.meta["mesh"],
             "worst_rel_err": worst,
             "lane_minutes_per_sec_1dev": one.meta["lane_minutes_per_sec"],
@@ -277,8 +294,10 @@ def phase_fleet_dp(spec: fleet.FleetSpec, n_devices: int):
 
 # --------------------------------------------------------------- main ----
 class CompileClock:
-    """Sums the backend compile seconds JAX reports (a persistent-cache
-    hit counts at its retrieval time)."""
+    """Sums the seconds JAX reports under its backend-compile event. That
+    event times ``compile_or_get_cached``, so a persistent-cache hit
+    counts with the time it took to look up and load the executable
+    (pinned in tests/test_chip_smoke.py)."""
 
     def __init__(self):
         self.seconds = 0.0

@@ -1,10 +1,9 @@
 """Pallas TPU kernel: whole-episode fused plant + controller advance.
 
-``plant_tick_block`` (kernels/plant_block.py) advances the decision-free
-ticks of one control period in VMEM but returns to XLA at every block
-head for ``controller.decide`` — so an M-minute episode still pays
-M x ceil(60/ci) kernel-boundary round trips, and the controller
-arithmetic never runs on-chip. This kernel fuses the entire episode:
+A kernel that advanced only the decision-free ticks of one control
+period would return to XLA at every block head for ``controller.decide``
+— an M-minute episode would pay M x ceil(60/ci) kernel-boundary round
+trips. This kernel fuses the entire episode:
 
 * grid = (lane tiles, minutes); the minute axis is sequential per tile,
   so the plant lanes, the startup pipeline, the rate history ring and

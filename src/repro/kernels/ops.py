@@ -15,7 +15,6 @@ from repro.core.features import freq_features
 from repro.kernels.episode_block import episode_minutes
 from repro.kernels.gbdt_tables import gbdt_logits_kernel
 from repro.kernels.holt_winters import holt_winters_kernel
-from repro.kernels.plant_block import plant_block_kernel
 from repro.kernels.window_features import window_features_kernel
 
 
@@ -49,26 +48,6 @@ def holt_winters(y: jax.Array, *, period: int = 60, alpha: float = 0.1,
     return holt_winters_kernel(y, period=period, alpha=alpha, beta=beta,
                                gamma=gamma, tile_b=tile_b,
                                interpret=interpret)
-
-
-def plant_tick_block(ready, pipeline, queue, wait_sum, util_ema, cooldown,
-                     pipe_sum, arrivals, *, n_ticks: int,
-                     rps_per_replica: float = 20.0,
-                     service_sec: float = 0.1, slo_sec: float = 0.5,
-                     resp_cap_sec: float = 600.0,
-                     metric_tau_sec: float = 60.0, tile_b: int = 8,
-                     interpret: bool | None = None):
-    """Advance [B] cluster-plant lanes a whole control period (`n_ticks`
-    seconds, no decisions) via the fused kernel. Contract of
-    ``repro.sim.cluster.plant_block_ref``: (state tuple, [B, T] ticks)."""
-    if interpret is None:
-        interpret = _default_interpret()
-    return plant_block_kernel(
-        ready, pipeline, queue, wait_sum, util_ema, cooldown, pipe_sum,
-        arrivals, n_ticks=n_ticks, rps_per_replica=rps_per_replica,
-        service_sec=service_sec, slo_sec=slo_sec,
-        resp_cap_sec=resp_cap_sec, metric_tau_sec=metric_tau_sec,
-        tile_b=tile_b, interpret=interpret)
 
 
 def episode_block(rates, controller, cfg, *, tile_b: int = 8,

@@ -23,23 +23,6 @@ def holt_winters_ref(y: jax.Array, *, period: int = 60, alpha: float = 0.1,
     return hw_smooth(y, period=period, alpha=alpha, beta=beta, gamma=gamma)
 
 
-def plant_block_ref(ready, pipeline, queue, wait_sum, util_ema, cooldown,
-                    pipe_sum, arrivals, *, n_ticks: int,
-                    rps_per_replica: float = 20.0, service_sec: float = 0.1,
-                    slo_sec: float = 0.5, resp_cap_sec: float = 600.0,
-                    metric_tau_sec: float = 60.0):
-    """[B] plant lanes advanced `n_ticks` seconds — identical math to the
-    blocked path in ``repro.sim.cluster`` (what the CPU sim runs)."""
-    from repro.sim.cluster import SimConfig
-    from repro.sim.cluster import plant_block_ref as _ref
-    cfg = SimConfig(rps_per_replica=rps_per_replica,
-                    service_sec=service_sec, slo_sec=slo_sec,
-                    resp_cap_sec=resp_cap_sec,
-                    metric_tau_sec=metric_tau_sec)
-    return _ref(cfg, ready, pipeline, queue, wait_sum, util_ema, cooldown,
-                pipe_sum, arrivals, n_ticks=n_ticks)
-
-
 def episode_block_ref(rates, controller, cfg):
     """rates [B, M] -> MinuteOut of [B, M]: the CPU blocked scan, one
     lane per workload — the dispatch oracle for the fused-decide episode

@@ -39,11 +39,11 @@ Two plant-cost levers keep the blocked path hot-loop cheap:
   update sequence in both paths), so plant ticks do O(1) work instead of
   an O(startup_sec) shift + reduction per tick.
 
-On TPU the plant-only ticks of a block dispatch to the fused Pallas
-kernel ``repro.kernels.plant_block`` (whole control period advanced in
-VMEM); on CPU the blocked path below *is* the reference oracle the
-kernel is property-tested against — the same kernel/ref dual-dispatch
-pattern as `window_features` and `holt_winters`.
+On TPU a whole episode of a `tpu_kernel` controller dispatches to the
+fused Pallas kernel ``repro.kernels.episode_block`` (plant ticks and
+`decide` in VMEM); the blocked path below *is* the reference oracle that
+kernel is pinned against — the same kernel/ref dual-dispatch pattern as
+`window_features` and `holt_winters`.
 
 This module is the *plant*; the control plane lives in `repro.scaling`:
 the Controller/Obs protocol and the cooldown semantics come from
@@ -68,7 +68,7 @@ from repro.scaling.api import (Controller, LimiterState, Obs,
 
 __all__ = ["Controller", "Obs", "SimConfig", "SimState", "MinuteOut",
            "advance_plant", "initial_state", "minute_step",
-           "minute_step_reference", "plant_block_ref", "simulate",
+           "minute_step_reference", "simulate",
            "simulate_reference", "make_simulator"]
 
 EPSF = 1e-9
@@ -297,47 +297,9 @@ def _minute_out(acc, state: SimState) -> MinuteOut:
 
 
 # --------------------------------------------------- plant-block advance ----
-def plant_block_ref(cfg: SimConfig, ready, pipeline, queue, wait_sum,
-                    util_ema, cooldown, pipe_sum, arrivals, *,
-                    n_ticks: int):
-    """Advance a lane-tile of plants `n_ticks` seconds with no control
-    decisions: the pure-jnp oracle for the fused Pallas kernel
-    (``repro.kernels.plant_block``). All state args are [B] (pipeline is
-    [B, startup_sec]); `arrivals` is the per-lane per-second rate.
-
-    Returns ``(state, ticks)`` where `state` is the tuple (ready,
-    pipeline, queue, wait_sum, util_ema, cooldown, pipe_sum) after the
-    block and `ticks` is the tuple (served, violated, cold,
-    total_replicas, resp, util, ready) of [B, n_ticks] per-tick
-    measurements."""
-    def one_lane(r, p, q, w, u, c, ps, a):
-        def body(carry, _):
-            r, p, q, w, u, c, ps = carry
-            popped = p[0]
-            r = r + popped
-            p = jnp.concatenate([p[1:], jnp.zeros((1,), jnp.float32)])
-            ps = jnp.maximum(ps - popped, 0.0)
-            q, w, u, served, violated, cold, resp, util = _flow_tick(
-                cfg, r, q, w, u, a)
-            c = jnp.maximum(c - 1.0, 0.0)
-            return ((r, p, q, w, u, c, ps),
-                    (served, violated, cold, r + ps, resp, util, r))
-        return jax.lax.scan(body, (r, p, q, w, u, c, ps), None,
-                            length=n_ticks)
-
-    state, ticks = jax.vmap(one_lane)(
-        jnp.asarray(ready, jnp.float32), jnp.asarray(pipeline, jnp.float32),
-        jnp.asarray(queue, jnp.float32), jnp.asarray(wait_sum, jnp.float32),
-        jnp.asarray(util_ema, jnp.float32),
-        jnp.asarray(cooldown, jnp.float32),
-        jnp.asarray(pipe_sum, jnp.float32),
-        jnp.asarray(arrivals, jnp.float32))
-    return state, ticks
-
-
 #: Unroll plant blocks up to this many ticks (covers control intervals
 #: through ~17 s, in particular the 15 s default); longer blocks scan
-#: (see _plant_block docstring).
+#: (see the advance_plant docstring).
 _UNROLL_MAX_TICKS = 16
 
 
@@ -406,49 +368,24 @@ def advance_plant(cfg: SimConfig, ready, pipeline, pipe_sum, queue,
 
 
 def _plant_block(cfg: SimConfig, state: SimState, acc,
-                 arrivals: jax.Array, n_ticks: int, use_kernel: bool):
-    """`n_ticks` plant-only ticks folded into the minute accumulator:
-    `advance_plant` (the kernel's parity oracle semantics), or with
-    `use_kernel` (`plant_kernel=True`) one fused `plant_tick_block`
-    kernel call advancing the whole block in VMEM."""
-    if not use_kernel:
-        (ready, pipeline, pipe_sum, queue, wait_sum, util_ema,
-         cool), acc = advance_plant(
-            cfg, state.ready, state.pipeline, state.pipe_sum, state.queue,
-            state.wait_sum, state.util_ema, state.lim.cooldown, acc,
-            arrivals, n_ticks)
-        state = state._replace(
-            ready=ready, pipeline=pipeline, pipe_sum=pipe_sum,
-            queue=queue, wait_sum=wait_sum, util_ema=util_ema,
-            lim=LimiterState(cooldown=cool, last_dir=state.lim.last_dir))
-        return state, acc
-
-    from repro.kernels import ops
-    (r, p, q, w, u, c, ps), ticks = ops.plant_tick_block(
-        state.ready[None], state.pipeline[None], state.queue[None],
-        state.wait_sum[None], state.util_ema[None],
-        state.lim.cooldown[None], state.pipe_sum[None],
-        jnp.asarray(arrivals)[None],
-        n_ticks=n_ticks, rps_per_replica=cfg.rps_per_replica,
-        service_sec=cfg.service_sec, slo_sec=cfg.slo_sec,
-        resp_cap_sec=cfg.resp_cap_sec, metric_tau_sec=cfg.metric_tau_sec)
+                 arrivals: jax.Array, n_ticks: int):
+    """`n_ticks` plant-only ticks folded into the minute accumulator
+    (`advance_plant` on the SimState fields)."""
+    (ready, pipeline, pipe_sum, queue, wait_sum, util_ema,
+     cool), acc = advance_plant(
+        cfg, state.ready, state.pipeline, state.pipe_sum, state.queue,
+        state.wait_sum, state.util_ema, state.lim.cooldown, acc,
+        arrivals, n_ticks)
     state = state._replace(
-        ready=r[0], pipeline=p[0], queue=q[0], wait_sum=w[0],
-        util_ema=u[0], pipe_sum=ps[0],
-        lim=LimiterState(cooldown=c[0], last_dir=state.lim.last_dir))
-    served, violated, cold, total, resp, util, ready = (
-        t[0] for t in ticks)                          # [n_ticks] each
-    acc = (acc[0] + jnp.sum(served), acc[1] + jnp.sum(violated),
-           acc[2] + jnp.sum(cold), acc[3] + jnp.sum(total),
-           acc[4] + jnp.sum(resp * served),
-           jnp.maximum(acc[5], jnp.max(resp)), acc[6], acc[7], acc[8],
-           acc[9] + jnp.sum(util), acc[10] + jnp.sum(ready))
+        ready=ready, pipeline=pipeline, pipe_sum=pipe_sum,
+        queue=queue, wait_sum=wait_sum, util_ema=util_ema,
+        lim=LimiterState(cooldown=cool, last_dir=state.lim.last_dir))
     return state, acc
 
 
 def _block(cfg: SimConfig, controller: Controller, state: SimState, acc,
-           arrivals, minute_idx, n_ticks: int, use_kernel: bool,
-           telemetry: bool = False, head_sec=0.0):
+           arrivals, minute_idx, n_ticks: int, telemetry: bool = False,
+           head_sec=0.0):
     """One control period: decide at the head tick, then `n_ticks - 1`
     plant-only ticks, all folded into the minute accumulator."""
     if telemetry:
@@ -458,19 +395,18 @@ def _block(cfg: SimConfig, controller: Controller, state: SimState, acc,
         acc = _acc_fold(acc, head)
         if n_ticks > 1:
             state, acc = _plant_block(cfg, state, acc, arrivals,
-                                      n_ticks - 1, use_kernel)
+                                      n_ticks - 1)
         return state, acc, rec
     state, head = _ctrl_tick(cfg, controller, state, arrivals, minute_idx,
                              True)
     acc = _acc_fold(acc, head)
     if n_ticks == 1:
         return state, acc
-    return _plant_block(cfg, state, acc, arrivals, n_ticks - 1, use_kernel)
+    return _plant_block(cfg, state, acc, arrivals, n_ticks - 1)
 
 
 def _minute_blocked(cfg: SimConfig, controller: Controller, carry,
-                    rate_this_min: jax.Array, use_kernel: bool = False,
-                    telemetry: bool = False):
+                    rate_this_min: jax.Array, telemetry: bool = False):
     """One minute = ceil(60/ci) control-period blocks + the minute-
     boundary controller hook. `decide` runs exactly once per block.
 
@@ -493,7 +429,7 @@ def _minute_blocked(cfg: SimConfig, controller: Controller, carry,
         def block_body(carry, head_sec):
             st, a = carry
             st, a, rec = _block(cfg, controller, st, a, arrivals_per_sec,
-                                minute_idx, ci, use_kernel, telemetry=True,
+                                minute_idx, ci, telemetry=True,
                                 head_sec=head_sec)
             return (st, a), rec
 
@@ -508,7 +444,7 @@ def _minute_blocked(cfg: SimConfig, controller: Controller, carry,
         if tail:
             state, acc, rec = _block(cfg, controller, state, acc,
                                      arrivals_per_sec, minute_idx, tail,
-                                     use_kernel, telemetry=True,
+                                     telemetry=True,
                                      head_sec=jnp.float32(n_full * ci))
             recs.append(jax.tree.map(lambda x: x[None], rec))
         decisions = (recs[0] if len(recs) == 1 else jax.tree.map(
@@ -525,17 +461,17 @@ def _minute_blocked(cfg: SimConfig, controller: Controller, carry,
     def block_body(carry, _):
         st, a = carry
         return _block(cfg, controller, st, a, arrivals_per_sec,
-                      minute_idx, ci, use_kernel), None
+                      minute_idx, ci), None
 
     if n_full == 1:      # a length-1 scan only obscures the block body
         state, acc = _block(cfg, controller, state, acc, arrivals_per_sec,
-                            minute_idx, ci, use_kernel)
+                            minute_idx, ci)
     elif n_full:
         (state, acc), _ = jax.lax.scan(block_body, (state, acc), None,
                                        length=n_full)
     if tail:
         state, acc = _block(cfg, controller, state, acc, arrivals_per_sec,
-                            minute_idx, tail, use_kernel)
+                            minute_idx, tail)
     return _finish_minute(cfg, controller, state, minute_idx,
                           rate_this_min, acc)
 
@@ -628,20 +564,16 @@ minute_step_reference = _minute_reference
 
 def simulate(rates_per_min: jax.Array, controller: Controller,
              cfg: SimConfig = SimConfig(), *,
-             plant_kernel: bool = False,
              decide_kernel: bool | None = None,
              telemetry: bool = False) -> MinuteOut:
     """Simulate one workload. rates_per_min [M] -> MinuteOut of [M] arrays.
 
     Control-period-blocked: `decide` runs once per control interval
     (bit-exact with `simulate_reference`, which evaluates it every tick).
-    `plant_kernel=True` advances the decision-free ticks with the fused
-    Pallas plant kernel (off by default: no chip measurement has shown
-    it winning); `decide_kernel=None` auto-selects the *whole-episode*
-    fused kernel (``repro.kernels.episode_block``) on TPU for a
-    controller that declares `tpu_kernel` (see `use_decide_kernel`) —
-    plant ticks and `decide` both on-chip, this blocked scan as its
-    dispatch oracle. `decide_kernel` subsumes `plant_kernel` when on.
+    `decide_kernel=None` auto-selects the *whole-episode* fused kernel
+    (``repro.kernels.episode_block``) on TPU for a controller that
+    declares `tpu_kernel` (see `use_decide_kernel`) — plant ticks and
+    `decide` both on-chip, this blocked scan as its dispatch oracle.
 
     `telemetry=True` (static) additionally captures the in-scan decision
     trace and returns ``(MinuteOut, ControlTrace)`` with decisions
@@ -657,8 +589,7 @@ def simulate(rates_per_min: jax.Array, controller: Controller,
                                 controller, cfg)
         return jax.tree.map(lambda a: a[0], out)
     (state, _), out = jax.lax.scan(
-        partial(_minute_blocked, cfg, controller, use_kernel=plant_kernel,
-                telemetry=telemetry),
+        partial(_minute_blocked, cfg, controller, telemetry=telemetry),
         (initial_state(controller, cfg), jnp.int32(0)),
         rates_per_min.astype(jnp.float32))
     return out
@@ -677,7 +608,6 @@ def simulate_reference(rates_per_min: jax.Array, controller: Controller,
 
 
 def make_simulator(controller: Controller, cfg: SimConfig = SimConfig(), *,
-                   plant_kernel: bool = False,
                    decide_kernel: bool | None = None,
                    w_chunk: int | None = None, donate: bool = False,
                    telemetry: bool = False):
@@ -707,7 +637,6 @@ def make_simulator(controller: Controller, cfg: SimConfig = SimConfig(), *,
             rates.astype(jnp.float32), controller, cfg)
     else:
         fn = jax.vmap(lambda r: simulate(r, controller, cfg,
-                                         plant_kernel=plant_kernel,
                                          decide_kernel=False,
                                          telemetry=telemetry))
 

@@ -4,7 +4,8 @@ ship hypothesis — property tests still run, just without shrinking)."""
 from __future__ import annotations
 
 try:
-    from hypothesis import given, settings, strategies as st  # noqa: F401
+    from hypothesis import example, given, settings  # noqa: F401
+    from hypothesis import strategies as st  # noqa: F401
 except ImportError:
     import numpy as np
 
@@ -29,6 +30,8 @@ except ImportError:
             # no functools.wraps: pytest must see a zero-arg signature,
             # not the wrapped function's strategy parameters
             def wrapper():
+                for args in getattr(fn, "_examples", ()):
+                    fn(*args)
                 n = getattr(wrapper, "_max_examples", 10)
                 rng = np.random.default_rng(0)
                 for _ in range(n):
@@ -36,6 +39,14 @@ except ImportError:
             wrapper.__name__ = fn.__name__
             wrapper.__doc__ = fn.__doc__
             return wrapper
+        return deco
+
+    def example(*args):
+        """An explicit case, replayed before the seeded ones; place it
+        between `@given` and the function."""
+        def deco(fn):
+            fn._examples = getattr(fn, "_examples", ()) + (args,)
+            return fn
         return deco
 
     def settings(max_examples=10, **_):

@@ -18,6 +18,7 @@ def _run(tmp_path, *args):
            "--json", str(tmp_path), *args]
     env = dict(os.environ)
     env["PYTHONPATH"] = str(REPO / "src")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jax_cache")
     subprocess.run(cmd, check=True, cwd=REPO, timeout=3000, env=env)
     with open(tmp_path / "BENCH_fleet.json") as f:
         return json.load(f)

@@ -13,6 +13,8 @@ import shutil
 import subprocess
 import sys
 
+import jax
+import jax.numpy as jnp
 import pytest
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
@@ -74,9 +76,43 @@ def test_phase_calibration(trained_and_built):
 def test_phase_fleet(trained_and_built):
     trained, _ = trained_and_built
     info = cs.phase_fleet(cs.fleet_spec(**TINY_FLEET),
-                          trained.make_classify(), stream_chunks=1)
-    assert info["workloads"] == 8 and info["stream_workloads"] == 4
-    assert info["chunk0_worst_rel_err"] < cs.METRIC_RTOL
+                          trained.make_classify(), stream_chunks=2)
+    assert info["workloads"] == 8 and info["stream_workloads"] == 8
+    assert info["stream_vs_dispatch_worst_rel_err"] <= cs.DP_RTOL
+    assert info["oracle_worst_rel_err"] < cs.METRIC_RTOL
+
+
+def _forgetful_fold(orig):
+    """The streaming fold with a fault that drops every earlier chunk."""
+    def make(spec_, classify=None):
+        fold = orig(spec_, classify)
+        return lambda acc, chunk: fold(
+            jax.tree.map(jnp.zeros_like, acc), chunk)
+    return make
+
+
+def _last_chunk_runner(orig):
+    """The one-dispatch runner with a fault that pools the last chunk
+    only."""
+    def make(spec_, classify=None, **kw):
+        run = orig(spec_, classify, **kw)
+        return lambda rates: run(rates[-1:])
+    return make
+
+
+@pytest.mark.parametrize("target, fault", [
+    ("make_chunk_folder", _forgetful_fold),
+    ("make_fleet_runner", _last_chunk_runner)])
+def test_phase_fleet_catches_cross_chunk_faults(target, fault,
+                                                trained_and_built,
+                                                monkeypatch):
+    """A fault that pools across chunks, in the streaming fold or in the
+    chunk scan of the one-dispatch runner, fails the fleet phase."""
+    trained, _ = trained_and_built
+    monkeypatch.setattr(cs.fleet, target, fault(getattr(cs.fleet, target)))
+    with pytest.raises(AssertionError):
+        cs.phase_fleet(cs.fleet_spec(**TINY_FLEET),
+                       trained.make_classify(), stream_chunks=2)
 
 
 def test_phase_matrix(trained_and_built):
@@ -103,6 +139,44 @@ def test_phase_fleet_dp_one_device():
     info = cs.phase_fleet_dp(cs.fleet_spec(**TINY_FLEET), 1)
     assert info["mesh"] == {"data": 1}
     assert info["worst_rel_err"] <= cs.DP_RTOL
+
+
+_CLOCK_PROBE = """
+import importlib.util, json, sys
+spec = importlib.util.spec_from_file_location("chip_smoke", sys.argv[1])
+cs = importlib.util.module_from_spec(spec)
+spec.loader.exec_module(cs)
+import jax, jax.numpy as jnp
+cs.compile_cache.enable()
+hits = []
+jax.monitoring.register_event_duration_secs_listener(
+    lambda e, d, **_: hits.append(d)
+    if e == "/jax/compilation_cache/cache_retrieval_time_sec" else None)
+clock = cs.CompileClock()
+jax.jit(lambda x: jnp.cos(x) * 3.0 - 1.0)(jnp.ones(8)).block_until_ready()
+print(json.dumps({"clock": clock.seconds, "retrieved": sum(hits),
+                  "hits": len(hits)}))
+"""
+
+
+def test_compile_clock_counts_cache_hits(tmp_path):
+    """`CompileClock` counts a cold compile, and in a second process a
+    persistent-cache hit with at least its retrieval time."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+
+    def probe():
+        res = subprocess.run(
+            [sys.executable, "-c", _CLOCK_PROBE, str(ROOT / "chip_smoke.py")],
+            env=env, capture_output=True, text=True, timeout=300)
+        assert res.returncode == 0, res.stderr[-2000:]
+        return json.loads(res.stdout.strip().splitlines()[-1])
+
+    cold, warm = probe(), probe()
+    assert cold["hits"] == 0 and cold["clock"] > 0
+    assert warm["hits"] >= 1
+    assert warm["clock"] >= warm["retrieved"] > 0
 
 
 def test_phase_lines_are_json(capsys):

@@ -1,6 +1,6 @@
 """The `decide_kernel=` dispatch contract (ISSUE: whole-episode-on-chip).
 
-Three guarantees, mirrored from the `plant_kernel=` flag:
+Three guarantees:
 
 * **off path is bit-exact** — `decide_kernel=False` and the CPU default
   (auto-off on non-TPU backends) produce byte-identical MinuteOut: the

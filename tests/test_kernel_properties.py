@@ -6,7 +6,7 @@ shapes — the regimes the fixed parametrized sweeps in test_kernels.py
 don't reach."""
 import numpy as np
 import jax.numpy as jnp
-from _hypo_compat import given, settings, st
+from _hypo_compat import example, given, settings, st
 
 from repro.kernels import ops, ref
 
@@ -45,68 +45,6 @@ def test_window_features_parity_any_shape(n, w, tile_n):
     np.testing.assert_allclose(got, want, rtol=5e-4, atol=5e-4)
 
 
-def _plant_state(rng, b, s):
-    """Random but physical lane state: non-negative, pipe_sum consistent
-    with the pipeline to within the incremental-update drift the sim
-    itself produces."""
-    pipeline = rng.gamma(1.0, 0.6, (b, s)).astype(np.float32)
-    return dict(
-        ready=rng.gamma(2.0, 2.0, b).astype(np.float32),
-        pipeline=pipeline,
-        queue=rng.gamma(1.0, 25.0, b).astype(np.float32),
-        wait_sum=rng.gamma(1.0, 5.0, b).astype(np.float32),
-        util_ema=rng.random(b).astype(np.float32),
-        cooldown=rng.uniform(0.0, 20.0, b).astype(np.float32),
-        pipe_sum=pipeline.sum(axis=1).astype(np.float32),
-        arrivals=rng.gamma(2.0, 30.0, b).astype(np.float32))
-
-
-@settings(max_examples=8, deadline=None)
-@given(st.integers(min_value=1, max_value=19),
-       st.integers(min_value=3, max_value=40),
-       st.integers(min_value=1, max_value=30),
-       st.integers(min_value=2, max_value=8))
-def test_plant_block_parity_any_shape(b, s, n_ticks, tile_b):
-    """Fused plant kernel == blocked-scan oracle for arbitrary lane
-    counts (including non-multiple-of-tile), startup depths, and control
-    periods."""
-    rng = np.random.default_rng(b * 7919 + s * 31 + n_ticks)
-    state = {k: jnp.asarray(v) for k, v in _plant_state(rng, b, s).items()}
-    ks, kt = ops.plant_tick_block(*state.values(), n_ticks=n_ticks,
-                                  tile_b=tile_b, interpret=True)
-    rs, rt = ref.plant_block_ref(*state.values(), n_ticks=n_ticks)
-    for i, (a, e) in enumerate(zip(ks, rs)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(e),
-                                   rtol=1e-5, atol=1e-5,
-                                   err_msg=f"state[{i}]")
-    for i, (a, e) in enumerate(zip(kt, rt)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(e),
-                                   rtol=1e-5, atol=1e-5,
-                                   err_msg=f"ticks[{i}]")
-
-
-@settings(max_examples=6, deadline=None)
-@given(st.integers(min_value=2, max_value=11),
-       st.integers(min_value=1, max_value=14))
-def test_plant_block_padding_lanes_inert(b, n_ticks):
-    """Appending lanes must not perturb the original lanes: the tile pad
-    region stays inert through the whole in-VMEM tick loop."""
-    rng = np.random.default_rng(b * 131 + n_ticks)
-    state = _plant_state(rng, b, 30)
-    solo_in = {k: jnp.asarray(v[:1]) for k, v in state.items()}
-    full_in = {k: jnp.asarray(v) for k, v in state.items()}
-    s1, t1 = ops.plant_tick_block(*solo_in.values(), n_ticks=n_ticks,
-                                  interpret=True)
-    sN, tN = ops.plant_tick_block(*full_in.values(), n_ticks=n_ticks,
-                                  interpret=True)
-    for a, e in zip(sN, s1):
-        np.testing.assert_allclose(np.asarray(a)[:1], np.asarray(e),
-                                   rtol=1e-6, atol=1e-6)
-    for a, e in zip(tN, t1):
-        np.testing.assert_allclose(np.asarray(a)[:1], np.asarray(e),
-                                   rtol=1e-6, atol=1e-6)
-
-
 _GBDT_CACHE: dict = {}
 
 
@@ -130,12 +68,15 @@ def _gbdt_params(seed, rounds, depth):
        st.integers(min_value=4, max_value=24),
        st.integers(min_value=2, max_value=6),
        st.integers(min_value=2, max_value=3))
+@example(5, 4, 4, 3)
 def test_gbdt_tables_parity_any_shape(n, tile_n, rounds, depth):
     """Node-table kernel == the host table path for arbitrary row counts
     (including non-multiple-of-tile), tile sizes, and tree geometries.
     Ulp-tight, not bitwise: the kernel and the host path are two
     compiled programs, and XLA may order the per-class leaf reduction
-    differently in each (see test_gbdt_tables_small_shape_parity)."""
+    differently in each. The pinned example (n=5, tile_n=4, rounds=4,
+    depth=3) differs in 2 of 20 logits by 1.19e-7 absolute, 5.2e-7
+    relative."""
     params = _gbdt_params(rounds * 10 + depth, rounds, depth)
     rng = np.random.default_rng(n * 7919 + tile_n)
     X = jnp.asarray(rng.normal(size=(n, 9)).astype(np.float32))

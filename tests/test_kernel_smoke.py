@@ -3,7 +3,7 @@
 The parametrized sweeps in test_kernels.py are nightly (`slow`) and
 test_kernel_properties.py degrades to seeded replay without hypothesis —
 this file is the per-PR floor: one fixed small shape per Pallas kernel
-(`plant_block`, `window_features`, `holt_winters`, the fused-decide
+(`window_features`, `holt_winters`, the fused-decide
 `episode_block` for every registry policy, and the GBDT node-table
 kernel), seconds to run, so a kernel regression is caught in the same CI
 pass that introduced it.
@@ -59,32 +59,6 @@ def test_holt_winters_small_shape_parity(t):
                                       interpret=True))
     want = np.asarray(ref.holt_winters_ref(jnp.asarray(y), period=24))
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
-
-
-def test_plant_block_small_shape_parity():
-    rng = np.random.default_rng(3)
-    b, s, n_ticks = 4, 30, 15
-    pipeline = rng.gamma(1.0, 0.6, (b, s)).astype(np.float32)
-    state = dict(
-        ready=rng.gamma(2.0, 2.0, b).astype(np.float32),
-        pipeline=pipeline,
-        queue=rng.gamma(1.0, 25.0, b).astype(np.float32),
-        wait_sum=rng.gamma(1.0, 5.0, b).astype(np.float32),
-        util_ema=rng.random(b).astype(np.float32),
-        cooldown=rng.uniform(0.0, 20.0, b).astype(np.float32),
-        pipe_sum=pipeline.sum(axis=1).astype(np.float32),
-        arrivals=rng.gamma(2.0, 30.0, b).astype(np.float32))
-    args = [jnp.asarray(v) for v in state.values()]
-    ks, kt = ops.plant_tick_block(*args, n_ticks=n_ticks, interpret=True)
-    rs, rt = ref.plant_block_ref(*args, n_ticks=n_ticks)
-    for i, (a, e) in enumerate(zip(ks, rs)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(e),
-                                   rtol=1e-5, atol=1e-5,
-                                   err_msg=f"state[{i}]")
-    for i, (a, e) in enumerate(zip(kt, rt)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(e),
-                                   rtol=1e-5, atol=1e-5,
-                                   err_msg=f"ticks[{i}]")
 
 
 # ci=30 keeps the unrolled-tick jaxpr small (the 29-tick remainder goes

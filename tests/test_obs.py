@@ -336,6 +336,7 @@ def test_bench_profile_writes_trace_dir(tmp_path):
     directory per bench (what the nightly CI job uploads)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = str(REPO / "src")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jax_cache")
     subprocess.run(
         [sys.executable, "-m", "benchmarks.run", "sim", "--smoke",
          "--json", str(tmp_path), "--profile", str(tmp_path / "prof")],

@@ -24,6 +24,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from _hypo_compat import given, settings, st
 
 from repro.scaling import registry, scenarios
 from repro.scaling.api import Controller
@@ -151,28 +152,63 @@ def test_blocked_is_the_default_everywhere():
     assert SC.minute_step_reference is SC._minute_reference
 
 
-def test_plant_kernel_path_matches_scan_path():
-    """The fused Pallas plant kernel (interpret mode on CPU) wired into
-    simulate via plant_kernel=True reproduces the scan path, vmapped and
-    not."""
-    cfg = SimConfig()
-    rng = np.random.default_rng(5)
-    ctrl = registry.get_controller("hpa", cfg)
-    rates = jnp.asarray(rng.poisson(1100, 12).astype(np.float32))
-    a = simulate(rates, ctrl, cfg)
-    b = simulate(rates, ctrl, cfg, plant_kernel=True)
-    for field in a._fields:
-        np.testing.assert_allclose(
-            np.asarray(getattr(a, field)), np.asarray(getattr(b, field)),
-            rtol=1e-5, atol=1e-4, err_msg=field)
+def _plant_lanes(rng, b, s):
+    """Random but physical lane state for `advance_plant`: non-negative,
+    pipe_sum consistent with the pipeline, a zero minute accumulator."""
+    pipeline = rng.gamma(1.0, 0.6, (b, s)).astype(np.float32)
+    f32 = lambda x: jnp.asarray(x, jnp.float32)  # noqa: E731
+    return dict(
+        ready=f32(rng.gamma(2.0, 2.0, b)), pipeline=f32(pipeline),
+        pipe_sum=f32(pipeline.sum(axis=1)),
+        queue=f32(rng.gamma(1.0, 25.0, b)),
+        wait_sum=f32(rng.gamma(1.0, 5.0, b)),
+        util_ema=f32(rng.random(b)),
+        cooldown=f32(rng.uniform(0.0, 20.0, b)),
+        acc=tuple(jnp.zeros((b,), jnp.float32) for _ in range(11)),
+        arrivals=f32(rng.gamma(2.0, 30.0, b)))
 
-    batched = jnp.asarray(rng.poisson(800, (2, 12)).astype(np.float32))
-    kern = jax.jit(jax.vmap(
-        lambda r: simulate(r, ctrl, cfg, plant_kernel=True)))(batched)
-    scan = jax.jit(jax.vmap(lambda r: simulate(r, ctrl, cfg)))(batched)
-    np.testing.assert_allclose(np.asarray(kern.served),
-                               np.asarray(scan.served),
-                               rtol=1e-5, atol=1e-4)
+
+def _advance(lanes, n_ticks, cfg=SimConfig()):
+    state, acc = SC.advance_plant(cfg, *lanes.values(), n_ticks)
+    return [np.asarray(x) for x in (*state, *acc)]
+
+
+@settings(max_examples=8, deadline=None)
+@given(st.integers(min_value=1, max_value=9),
+       st.integers(min_value=3, max_value=40),
+       st.integers(min_value=1, max_value=SC._UNROLL_MAX_TICKS))
+def test_advance_plant_unrolled_matches_scan(b, s, n_ticks):
+    """The unrolled short-block form of `advance_plant` (static pipeline
+    reads, one shift at block end, one cooldown step) is bit-identical
+    op-for-op to the per-tick scan it replaces, including blocks longer
+    than the startup pipeline (s < n_ticks)."""
+    lanes = _plant_lanes(np.random.default_rng(b * 7919 + s * 31 + n_ticks),
+                         b, s)
+    with jax.disable_jit():
+        unrolled = _advance(lanes, n_ticks)
+        limit, SC._UNROLL_MAX_TICKS = SC._UNROLL_MAX_TICKS, 0
+        try:
+            scanned = _advance(lanes, n_ticks)
+        finally:
+            SC._UNROLL_MAX_TICKS = limit
+    for i, (u, c) in enumerate(zip(unrolled, scanned)):
+        np.testing.assert_array_equal(u, c, err_msg=f"output[{i}]")
+
+
+@settings(max_examples=6, deadline=None)
+@given(st.integers(min_value=2, max_value=11),
+       st.integers(min_value=1, max_value=45))
+def test_advance_plant_lanes_independent(b, n_ticks):
+    """`advance_plant` is shape-agnostic: on a batch of lanes (the fused
+    P x W layout of scaling.batch) each lane evolves exactly as it does
+    alone, in both the unrolled and the scanned regime."""
+    lanes = _plant_lanes(np.random.default_rng(b * 131 + n_ticks), b, 30)
+    solo = {k: (tuple(a[:1] for a in v) if k == "acc" else v[:1])
+            for k, v in lanes.items()}
+    with jax.disable_jit():
+        many, one = _advance(lanes, n_ticks), _advance(solo, n_ticks)
+    for i, (m, o) in enumerate(zip(many, one)):
+        np.testing.assert_array_equal(m[:1], o, err_msg=f"output[{i}]")
 
 
 @pytest.mark.slow

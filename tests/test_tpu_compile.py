@@ -5,9 +5,9 @@ the TPU compiler (Mosaic) compiles each kernel for it at the shapes
 ``chip_smoke.py`` runs, so a kernel the chip would refuse fails here.
 Each test asserts the compiled program holds the kernel
 (`tpu_custom_call`). Covered: `window_features` (dataset build),
-`holt_winters` (every Holt-Winters `smooth` on TPU), `plant_block`
-(`plant_kernel=True`), and `episode_block` for every policy the
-`decide_kernel` auto rule sends to it (`Controller.tpu_kernel`).
+`holt_winters` (every Holt-Winters `smooth` on TPU), and `episode_block`
+for every policy the `decide_kernel` auto rule sends to it
+(`Controller.tpu_kernel`).
 
 The topology is described inside a module fixture — never at import —
 so under pytest-xdist only the worker that runs this file loads the TPU
@@ -74,13 +74,6 @@ def test_holt_winters_compiles(one_chip, no_compile_cache):
     txt = _compile_text(
         lambda y: ops.holt_winters(y, interpret=False), (16, 2880),
         sharding=one_chip)
-    assert "tpu_custom_call" in txt
-
-
-def test_plant_block_compiles(one_chip, no_compile_cache):
-    txt = _compile_text(
-        lambda *a: ops.plant_tick_block(*a, n_ticks=14, interpret=False),
-        (8,), (8, 30), *[(8,)] * 6, sharding=one_chip)
     assert "tpu_custom_call" in txt
 
 
