@@ -14,10 +14,13 @@ Two execution modes, one compiled chunk body:
   chunks inside jit, chunk accumulators tree-summed in the carry. The
   W=1e5 decade of BENCH_fleet.json runs this way (acceptance: peak host
   memory < 2x the W=1e4 run, because only the rates tensor grows).
-* ``make_chunk_folder`` — streaming: a jitted (accum, chunk) -> accum
-  fold with the accumulator donated, driven by a host generator
+* ``make_chunk_folder`` — streaming: a host function (accum, chunk) ->
+  accum that puts each chunk on the device and calls the jitted fold
+  with the accumulator donated, driven by a host generator
   (``rate_chunks`` here or ``aapaset.AAPAsetLoader.rate_chunks``). Rates
-  never materialize beyond one chunk — this is the 1e6-lane mode.
+  never materialize beyond one chunk — this is the 1e6-lane mode. The
+  transfer and the fold call are the ``fleet.put`` and ``fleet.fold``
+  host spans of a profiler trace (``repro.obs.stages``).
 
 Under an active ``repro.dist.sharding`` mesh the chunk's workload axis
 shards over "dp" (each device advances its slice of every policy's
@@ -39,6 +42,7 @@ from repro.dist import sharding as shd
 from repro.evals import metrics as EM
 from repro.evals import rei as ER
 from repro.evals.matrix import _lane_runner
+from repro.obs.stages import FLEET_FOLD, FLEET_PUT
 from repro.scaling import registry, scenarios
 from repro.sim.cluster import SimConfig
 
@@ -169,11 +173,9 @@ def make_fleet_runner(spec_: FleetSpec, classify=None, *,
     return jax.jit(run, donate_argnums=(0,) if donate else ())
 
 
-def make_chunk_folder(spec_: FleetSpec, classify=None):
+def _jit_fold(spec_: FleetSpec, classify=None):
     """jit with a DONATED accumulator: (MetricAccum [P], rates [Wc, M])
-    -> MetricAccum [P]. The streaming fold for generator-fed fleets —
-    host memory is one chunk of rates + one O(P * bins) accumulator,
-    so W is bounded by wall clock, not memory."""
+    -> MetricAccum [P], the device program of `make_chunk_folder`."""
     cfg = spec_.sim_config()
     ctrls = controllers(spec_, classify)
     edges = EM.response_edges(spec_.bins, cfg.resp_cap_sec)
@@ -184,6 +186,25 @@ def make_chunk_folder(spec_: FleetSpec, classify=None):
         return jax.tree.map(jnp.add, acc, lanes(chunk))
 
     return jax.jit(fold, donate_argnums=(0,))
+
+
+def make_chunk_folder(spec_: FleetSpec, classify=None):
+    """(MetricAccum [P], rates [Wc, M]) -> MetricAccum [P], the
+    streaming fold for generator-fed fleets: host memory is one chunk of
+    rates + one O(P * bins) accumulator, so W is bounded by wall clock,
+    not memory. Each call puts the chunk on the device with the lanes'
+    sharding (the ``fleet.put`` host span) and calls the jitted fold,
+    which DONATES the accumulator (the ``fleet.fold`` span)."""
+    jfold = _jit_fold(spec_, classify)
+
+    def folder(acc, chunk):
+        with jax.profiler.TraceAnnotation(FLEET_PUT):
+            chunk = jax.device_put(
+                chunk, shd.lane_sharding(np.shape(chunk), w_axis=0))
+        with jax.profiler.TraceAnnotation(FLEET_FOLD):
+            return jfold(acc, chunk)
+
+    return folder
 
 
 class FleetResult(NamedTuple):

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from repro.dist import sharding as shd
 from repro.evals import metrics as EM
 from repro.evals import rei as ER
+from repro.obs.stages import METRIC_FOLD, stage
 from repro.scaling import batch, registry, scenarios
 from repro.sim.cluster import SimConfig
 
@@ -160,7 +161,8 @@ def _lane_runner(ctrls, cfg, edges, *, per_workload: bool = True,
     per controller per control step (`scaling.batch.make_batch_minute_
     step`), folding each minute into per-lane MetricAccums in the scan
     carry — the shared core of the matrix runner, the ad-hoc controller
-    evaluator, and the fleet runner. Memory stays O(bins) per lane.
+    evaluator, and the fleet runner. Memory stays O(bins) per lane. The
+    fold runs under the ``lane.metric_fold`` stage (``repro.obs.stages``).
 
     With ``per_workload=False`` the workload axis reduces *inside* the
     scan (`EM.accum_update_pooled`) and the leaves are [P, ...]: the
@@ -197,10 +199,11 @@ def _lane_runner(ctrls, cfg, edges, *, per_workload: bool = True,
             else:
                 st, m = step(st, idx, rate_w)
                 ct = None
-            acc = fold(acc, m)
-            if shard and per_workload:
-                acc = jax.tree.map(
-                    lambda a: shd.constrain(a, (None, "dp")), acc)
+            with stage(METRIC_FOLD):
+                acc = fold(acc, m)
+                if shard and per_workload:
+                    acc = jax.tree.map(
+                        lambda a: shd.constrain(a, (None, "dp")), acc)
             return (st, idx + 1, acc), ct
 
         (_, _, acc), ct = jax.lax.scan(

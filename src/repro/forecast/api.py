@@ -26,6 +26,8 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.stages import FORECAST, stage
+
 RESID_RHO = 0.05         # EWMA rate for the one-step residual scale
 NATIVE_Z = 1.64          # ~90% band under a Gaussian residual model
 EPSF = 1e-9
@@ -84,24 +86,28 @@ def make_forecaster(name: str, *, init_inner, update_inner, point_fn,
     ``init_inner() -> inner``, ``update_inner(inner, y) -> inner``,
     ``point_fn(inner, horizon) -> peak point forecast``. Residual
     tracking, the native interval, and (unless `smooth_fn` is given) the
-    scan-based offline backtest are shared here.
+    scan-based offline backtest are shared here. `update` and `forecast`
+    run under the ``lane.forecast`` stage.
     """
 
     def init() -> FState:
         return FState(inner=init_inner(), resid=jnp.float32(0.0))
 
     def update(state: FState, y) -> FState:
-        y = jnp.asarray(y, jnp.float32)
-        pred1 = point_fn(state.inner, 1)
-        resid = state.resid + RESID_RHO * (jnp.abs(y - pred1) - state.resid)
-        return FState(inner=update_inner(state.inner, y), resid=resid)
+        with stage(FORECAST):
+            y = jnp.asarray(y, jnp.float32)
+            pred1 = point_fn(state.inner, 1)
+            resid = state.resid + RESID_RHO * (jnp.abs(y - pred1)
+                                               - state.resid)
+            return FState(inner=update_inner(state.inner, y), resid=resid)
 
     def forecast(state: FState, horizon: int) -> Interval:
-        point = point_fn(state.inner, horizon)
-        half = z * state.resid * jnp.sqrt(jnp.float32(horizon))
-        return Interval(point=point,
-                        lo=jnp.maximum(point - half, 0.0),
-                        hi=point + half)
+        with stage(FORECAST):
+            point = point_fn(state.inner, horizon)
+            half = z * state.resid * jnp.sqrt(jnp.float32(horizon))
+            return Interval(point=point,
+                            lo=jnp.maximum(point - half, 0.0),
+                            hi=point + half)
 
     def smooth(y: jax.Array) -> jax.Array:
         """[..., T] -> one-step-ahead point forecasts [..., T]."""

@@ -16,6 +16,10 @@ every violated minute to the decision stage that caused it.
                   summary + decision timeline markdown) on the
                   ``aapaset.manifest`` staged-publish scheme, rendered
                   into EXPERIMENTS.md by ``tools/render_experiments``.
+* ``stages``    — the lane core's stage names (``jax.named_scope``
+                  around plant, decide, forecast, reclassification and
+                  the metric fold) and the streaming feed's host span
+                  names, which ``perfbench`` splits a profiler trace by.
 
 Only ``trace`` loads eagerly (it is dependency-free and imported by the
 sim core); ``attribute`` / ``artifacts`` resolve lazily because they

@@ -56,6 +56,7 @@ import jax.numpy as jnp
 
 from repro.dist import sharding as shd
 from repro.obs import trace as obs_trace
+from repro.obs.stages import DECIDE, PLANT, stage
 from repro.scaling import registry
 from repro.scaling.api import (Controller, LimiterState, Obs,
                                apply_decision)
@@ -121,56 +122,62 @@ def _batch_ctrl_tick(cfg, ctrls, state: BatchState, acc, arr_w,
     The plant pieces are cluster.py's own shape-agnostic helpers, so the
     batched and single-lane dynamics cannot drift apart. `telemetry`
     (static) additionally returns a [P, W] DecisionRecord; the False
-    path is op-for-op the pre-telemetry program."""
-    ready, pipeline, pipe_sum = _pop_pipeline(
-        state.ready, state.pipeline, state.pipe_sum)
+    path is op-for-op the pre-telemetry program. The plant pieces run
+    under the ``lane.plant`` stage, the decides under ``lane.decide``."""
+    with stage(PLANT):
+        ready, pipeline, pipe_sum = _pop_pipeline(
+            state.ready, state.pipeline, state.pipe_sum)
 
-    arr_pw = jnp.broadcast_to(arr_w, ready.shape)
-    (queue, wait_sum, util_ema, served, violated, cold, resp,
-     util) = _flow_tick(cfg, ready, state.queue, state.wait_sum,
-                        state.util_ema, arr_pw)
+        arr_pw = jnp.broadcast_to(arr_w, ready.shape)
+        (queue, wait_sum, util_ema, served, violated, cold, resp,
+         util) = _flow_tick(cfg, ready, state.queue, state.wait_sum,
+                            state.util_ema, arr_pw)
+        total = ready + pipe_sum
 
     W = arr_w.shape[0]
-    total = ready + pipe_sum
     new_ctrl, desired, cool_req, exps = [], [], [], []
-    for p, c in enumerate(ctrls):
-        obs = Obs(ready_total=total[p], ready=ready[p],
-                  util_ema=util_ema[p], queue=queue[p], rate_rps=arr_w,
-                  rate_history=state.rate_history, minute_idx=minute_idx)
-        cs, des, coo = jax.vmap(
-            c.decide, in_axes=(0, Obs(0, 0, 0, 0, 0, 0, None)))(
-                state.ctrl[p], obs)
-        new_ctrl.append(cs)
-        desired.append(jnp.asarray(des, jnp.float32))
-        cool_req.append(jnp.broadcast_to(
-            jnp.asarray(coo, jnp.float32), (W,)))
-        if telemetry:
-            exps.append(jax.vmap(
-                c.explain, in_axes=(0, Obs(0, 0, 0, 0, 0, 0, None)))(
+    with stage(DECIDE):
+        for p, c in enumerate(ctrls):
+            obs = Obs(ready_total=total[p], ready=ready[p],
+                      util_ema=util_ema[p], queue=queue[p], rate_rps=arr_w,
+                      rate_history=state.rate_history,
+                      minute_idx=minute_idx)
+            cs, des, coo = jax.vmap(
+                c.decide, in_axes=(0, Obs(0, 0, 0, 0, 0, 0, None)))(
                     state.ctrl[p], obs)
-                if getattr(c, "explain", None) is not None
-                else obs_trace.explain_nan((W,)))
-    desired_raw = jnp.stack(desired)
-    desired = jnp.clip(desired_raw, 0.0, cfg.max_replicas)
-    cool_req = jnp.stack(cool_req)
+            new_ctrl.append(cs)
+            desired.append(jnp.asarray(des, jnp.float32))
+            cool_req.append(jnp.broadcast_to(
+                jnp.asarray(coo, jnp.float32), (W,)))
+            if telemetry:
+                exps.append(jax.vmap(
+                    c.explain, in_axes=(0, Obs(0, 0, 0, 0, 0, 0, None)))(
+                        state.ctrl[p], obs)
+                    if getattr(c, "explain", None) is not None
+                    else obs_trace.explain_nan((W,)))
+    with stage(PLANT):
+        desired_raw = jnp.stack(desired)
+        desired = jnp.clip(desired_raw, 0.0, cfg.max_replicas)
+        cool_req = jnp.stack(cool_req)
 
-    cooldown_before = state.cooldown
-    lim, act = apply_decision(
-        LimiterState(cooldown=state.cooldown, last_dir=state.last_dir),
-        total, desired, cool_req, jnp.bool_(True), dt=1.0)
-    ready_at_decision = ready
-    ready, pipeline, pipe_sum = _apply_scaling(ready, pipeline, pipe_sum,
-                                               act)
+        cooldown_before = state.cooldown
+        lim, act = apply_decision(
+            LimiterState(cooldown=state.cooldown, last_dir=state.last_dir),
+            total, desired, cool_req, jnp.bool_(True), dt=1.0)
+        ready_at_decision = ready
+        ready, pipeline, pipe_sum = _apply_scaling(ready, pipeline,
+                                                   pipe_sum, act)
 
-    state = BatchState(ready=ready, pipeline=pipeline, pipe_sum=pipe_sum,
-                       queue=queue, wait_sum=wait_sum, util_ema=util_ema,
-                       cooldown=lim.cooldown, last_dir=lim.last_dir,
-                       rate_history=state.rate_history,
-                       ctrl=tuple(new_ctrl))
-    acc = _acc_fold(acc, (served, violated, cold, ready + pipe_sum, resp,
-                          util, act.scale_up.astype(jnp.float32),
-                          act.scale_down.astype(jnp.float32),
-                          act.oscillation, ready))
+        state = BatchState(ready=ready, pipeline=pipeline,
+                           pipe_sum=pipe_sum, queue=queue,
+                           wait_sum=wait_sum, util_ema=util_ema,
+                           cooldown=lim.cooldown, last_dir=lim.last_dir,
+                           rate_history=state.rate_history,
+                           ctrl=tuple(new_ctrl))
+        acc = _acc_fold(acc, (served, violated, cold, ready + pipe_sum,
+                              resp, util, act.scale_up.astype(jnp.float32),
+                              act.scale_down.astype(jnp.float32),
+                              act.oscillation, ready))
     if not telemetry:
         return state, acc
     exp = jax.tree.map(lambda *xs: jnp.stack(xs), *exps)      # [P, W]
@@ -186,11 +193,12 @@ def _batch_ctrl_tick(cfg, ctrls, state: BatchState, acc, arr_w,
 def _batch_plant_block(cfg, state: BatchState, acc, arr_pw, n_ticks: int):
     """`n_ticks` decision-free ticks for all [P, W] lanes — exactly
     cluster.advance_plant on the batched fields."""
-    (ready, pipeline, pipe_sum, queue, wait_sum, util_ema,
-     cool), acc = advance_plant(
-        cfg, state.ready, state.pipeline, state.pipe_sum, state.queue,
-        state.wait_sum, state.util_ema, state.cooldown, acc, arr_pw,
-        n_ticks)
+    with stage(PLANT):
+        (ready, pipeline, pipe_sum, queue, wait_sum, util_ema,
+         cool), acc = advance_plant(
+            cfg, state.ready, state.pipeline, state.pipe_sum, state.queue,
+            state.wait_sum, state.util_ema, state.cooldown, acc, arr_pw,
+            n_ticks)
     state = state._replace(
         ready=ready, pipeline=pipeline, pipe_sum=pipe_sum, queue=queue,
         wait_sum=wait_sum, util_ema=util_ema, cooldown=cool)
@@ -228,9 +236,11 @@ def make_batch_minute_step(controllers: Sequence[Controller],
             state = constrain_lanes(state)
             rate_w = shd.constrain(rate_w, ("dp",))
         W = rate_w.shape[0]
-        arr_w = rate_w / 60.0
-        arr_pw = jnp.broadcast_to(arr_w, (P, W))
-        acc = tuple(jnp.zeros((P, W), jnp.float32) for _ in _acc_init())
+        with stage(PLANT):
+            arr_w = rate_w / 60.0
+            arr_pw = jnp.broadcast_to(arr_w, (P, W))
+            acc = tuple(jnp.zeros((P, W), jnp.float32)
+                        for _ in _acc_init())
 
         if telemetry:
             return _step_telemetry(state, minute_idx, rate_w, arr_w,
@@ -296,18 +306,20 @@ def make_batch_minute_step(controllers: Sequence[Controller],
                                                  minutes=mt))
 
     def _finish(state, minute_idx, rate_w, acc):
-        m = MinuteOut(
-            served=acc[0], violated=acc[1], cold_starts=acc[2],
-            replica_seconds=acc[3], queue_end=state.queue, resp_sum=acc[4],
-            resp_max=acc[5], ups=acc[6], downs=acc[7], oscillations=acc[8],
-            util_mean=acc[9] / 60.0, ready_mean=acc[10] / 60.0)
-
-        hist = jnp.concatenate(
-            [state.rate_history[:, 1:], rate_w[:, None]], axis=1)
-        ctrl = tuple(
-            jax.vmap(c.on_minute, in_axes=(0, 0, None))(s, hist,
-                                                        minute_idx + 1)
-            for c, s in zip(ctrls, state.ctrl))
+        with stage(PLANT):
+            m = MinuteOut(
+                served=acc[0], violated=acc[1], cold_starts=acc[2],
+                replica_seconds=acc[3], queue_end=state.queue,
+                resp_sum=acc[4], resp_max=acc[5], ups=acc[6],
+                downs=acc[7], oscillations=acc[8],
+                util_mean=acc[9] / 60.0, ready_mean=acc[10] / 60.0)
+            hist = jnp.concatenate(
+                [state.rate_history[:, 1:], rate_w[:, None]], axis=1)
+        with stage(DECIDE):
+            ctrl = tuple(
+                jax.vmap(c.on_minute, in_axes=(0, 0, None))(
+                    s, hist, minute_idx + 1)
+                for c, s in zip(ctrls, state.ctrl))
         state = state._replace(rate_history=hist, ctrl=ctrl)
         return state, m
 

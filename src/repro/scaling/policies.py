@@ -36,6 +36,7 @@ from repro.core.archetypes import table_iii_arrays
 from repro.forecast import api as fapi
 from repro.forecast import conformal as fconf
 from repro.forecast import registry as forecast_registry
+from repro.obs.stages import RECLASSIFY, stage
 from repro.obs.trace import ExplainOut
 from repro.scaling.api import Controller, Obs
 
@@ -201,17 +202,18 @@ def aapa_controller(
         fst = fcst.update(state.fc, hist[-1])
 
         def reclassify(_):
-            feats = F.extract_features(hist)
-            arch, conf = classify(feats)
-            if forecast_confidence:
-                iv = fcst.forecast(fst, horizon_min)
-                conf = conf * fapi.interval_confidence(iv, conf_scale)
-            adj = uncertainty.adjust(conf,
-                                     _select4(arch, *tab["target_cpu"]),
-                                     _select4(arch, *tab["cooldown_min"]),
-                                     _select4(arch, *tab["min_replicas"]))
-            return AAPAState(fst, arch, conf, adj.target_cpu,
-                             adj.cooldown_min, adj.min_replicas)
+            with stage(RECLASSIFY):
+                feats = F.extract_features(hist)
+                arch, conf = classify(feats)
+                if forecast_confidence:
+                    iv = fcst.forecast(fst, horizon_min)
+                    conf = conf * fapi.interval_confidence(iv, conf_scale)
+                adj = uncertainty.adjust(
+                    conf, _select4(arch, *tab["target_cpu"]),
+                    _select4(arch, *tab["cooldown_min"]),
+                    _select4(arch, *tab["min_replicas"]))
+                return AAPAState(fst, arch, conf, adj.target_cpu,
+                                 adj.cooldown_min, adj.min_replicas)
 
         def keep(_):
             return state._replace(fc=fst)
