@@ -22,6 +22,12 @@ construction); the host path additionally cache-blocks the tree axis
 The retained per-round scan (``predict_logits_scan``) is the parity
 oracle; the two differ only in logit summation order (reshape-sum vs
 sequential scan), so parity is bit-close, not bit-exact.
+
+One row at a time (``row_logits``, what the controllers' ``classify``
+runs per lane inside the simulation scan) takes the same tables with
+compares and selects only: bins by counting edges (``bin_row``) and
+each tree's leaf by a select over its leaves (``traverse_row``), with
+no binary-search loop, no tree-chunk loop and no gather.
 """
 from __future__ import annotations
 
@@ -58,15 +64,14 @@ def node_tables(feat: jax.Array, thresh: jax.Array,
         leaf=jnp.asarray(leaf, jnp.float32).reshape(R * K, L))
 
 
-def _descend(bits: jax.Array, leaf: jax.Array) -> jax.Array:
-    """bits [N, T, I] per-node go-right decisions, leaf [T, L] ->
-    per-tree leaf values [N, T]. The walk is pure vector selects: at
-    depth d the live node id picks this level's decision bit through a
-    <= 2^d-way `jnp.where` chain — no lane-dynamic gather, which is
-    exactly the form the Pallas node-table kernel vectorizes."""
+def _leaf_ids(bits: jax.Array) -> jax.Array:
+    """bits [N, T, I] per-node go-right decisions -> level-local leaf
+    ids [N, T]. The walk is pure vector selects: at depth d the live
+    node id picks this level's decision bit through a <= 2^d-way
+    `jnp.where` chain — no lane-dynamic gather, which is exactly the
+    form the Pallas node-table kernel vectorizes."""
     N, T, I = bits.shape
-    L = leaf.shape[-1]
-    depth = max(int(L).bit_length() - 1, 0)
+    depth = (I + 1).bit_length() - 1
     node = jnp.zeros((N, T), jnp.int32)
     for d in range(depth):
         base = (1 << d) - 1
@@ -74,6 +79,14 @@ def _descend(bits: jax.Array, leaf: jax.Array) -> jax.Array:
         for n in range(1, 1 << d):
             b = jnp.where(node == n, bits[:, :, base + n], b)
         node = node * 2 + b.astype(jnp.int32)
+    return node
+
+
+def _descend(bits: jax.Array, leaf: jax.Array) -> jax.Array:
+    """bits [N, T, I], leaf [T, L] -> per-tree leaf values [N, T]: the
+    `_leaf_ids` walk, then one (tree, leaf id) gather."""
+    T = leaf.shape[0]
+    node = _leaf_ids(bits)
     return leaf[jnp.arange(T, dtype=jnp.int32)[None, :], node]
 
 
@@ -326,6 +339,56 @@ def predict_logits(params: GBDTParams, X: jax.Array) -> jax.Array:
     tables = (params.tables if params.tables is not None
               else node_tables(params.feat, params.thresh, params.leaf))
     return table_logits(params.base, tables, xb, chunked=True)
+
+
+def bin_row(edges: jax.Array, x: jax.Array) -> jax.Array:
+    """One row x [F], edges [F, B-1] -> int32 bins [F], equal to
+    `bin_features`': each bin counts the feature's edges <= x, by
+    `searchsorted`'s compare-all method, which applies the same
+    comparator as its binary search (NaN and +-inf included) with no
+    loop and no gather."""
+    def per_feature(e, v):
+        return jnp.searchsorted(e, v, side="right", method="compare_all")
+    return jax.vmap(per_feature)(edges, x).astype(jnp.int32)
+
+
+def traverse_row(tables: NodeTables, xb: jax.Array) -> jax.Array:
+    """`traverse_tables` for one row of bins xb [F] -> per-tree leaf
+    values [T], equal to it: every (tree, node) split compares at once,
+    `_leaf_ids` walks the levels, and each tree's leaf value is a
+    select over its 2^depth leaves (exactly one matches) instead of a
+    (tree, leaf id) gather. Each node's feature bin is picked by a
+    product with a one-hot [F, T*I] matrix, not a column gather: bins
+    are integers below 2^8 and the matrix holds 0 and 1, which bfloat16
+    holds exactly, and each column sums one product, so the product is
+    exact at any matmul precision."""
+    T, I = tables.feat.shape
+    F, L = xb.shape[0], tables.leaf.shape[-1]
+    onehot = tables.feat.reshape(1, -1) == jnp.arange(F)[:, None]
+    xv = xb.astype(jnp.float32) @ onehot.astype(jnp.float32)  # [T*I]
+    bits = (xv > tables.thresh.reshape(-1)).reshape(1, T, I)
+    node = _leaf_ids(bits)[0]                            # [T]
+    vals = tables.leaf[:, 0]
+    for n in range(1, L):
+        vals = jnp.where(node == n, tables.leaf[:, n], vals)
+    return vals
+
+
+@jax.jit
+def row_logits(params: GBDTParams, x: jax.Array) -> jax.Array:
+    """One feature row x [F] -> logits [K] by compares, selects and a
+    one-hot product (`bin_row`, `traverse_row`, then `table_logits`'
+    per-class reshape-sum): the form for one row per lane inside a
+    vmapped scan, where `predict_logits`' binary search, tree-chunk
+    loop and leaf gather are serial, run-time-indexed loads. Bins and
+    leaf values equal `predict_logits`'. Batch inference keeps
+    `predict_logits`: at large N its cache-blocked gathers cost far
+    less than this path's [N, F, B-1] compare plane and 2^depth selects
+    per tree."""
+    xb = bin_row(params.bin_edges, x.astype(jnp.float32))
+    vals = traverse_row(params.tables, xb)               # [T]
+    K = params.base.shape[0]
+    return params.base + vals.reshape(-1, K).sum(axis=0)
 
 
 @jax.jit

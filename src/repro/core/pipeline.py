@@ -41,13 +41,15 @@ class TrainedAAPA:
     dataset_id: str = ""       # "name-hash12" when trained from an artifact
 
     def make_classify(self) -> Callable:
-        """Returns classify(features [38]) -> (class int32, confidence)."""
+        """Returns classify(features [38]) -> (class int32, confidence).
+        It evaluates one row (`gbdt.row_logits`): controllers call it
+        per lane inside the vmapped minute scan."""
         params, cal = self.params, self.cal
 
         def classify(feats: jax.Array):
-            logits = gbdt.predict_logits(params, feats[None, :])
+            logits = gbdt.row_logits(params, feats)
             probs = jax.nn.softmax(logits, axis=-1)
-            calp = calibration.calibrate(cal, probs)[0]
+            calp = calibration.calibrate(cal, probs)
             return (jnp.argmax(calp).astype(jnp.int32),
                     jnp.max(calp).astype(jnp.float32))
 

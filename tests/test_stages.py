@@ -86,14 +86,17 @@ def test_every_stage_names_ops_of_the_compiled_program(programs, program):
 def test_gbdt_gather_and_fold_searchsorted_resolve_to_their_stages(
         programs, program):
     hlo = programs[program]
-    # the GBDT node-table gather: jnp.take of the binned features
-    node_gather = _stages_of(hlo, lambda n: "predict_logits" in n
-                             and "_take" in n and n.endswith("gather"))
-    assert node_gather == {stages.RECLASSIFY}
+    # the GBDT row evaluator, and in it the node-table pick of the
+    # binned features (a one-hot product where the host path gathers)
+    row = _stages_of(hlo, lambda n: "row_logits" in n)
+    assert row == {stages.RECLASSIFY}
+    node_pick = _stages_of(hlo, lambda n: "row_logits" in n
+                           and n.endswith("dot_general"))
+    assert node_pick == {stages.RECLASSIFY}
     # the metric fold's histogram searchsorted (binning the features is
     # a searchsorted of reclassification's own)
     fold_search = _stages_of(hlo, lambda n: "searchsorted" in n
-                             and "bin_features" not in n)
+                             and "row_logits" not in n)
     assert fold_search == {stages.METRIC_FOLD}
 
 

@@ -7,16 +7,20 @@ Each test asserts the compiled program holds the kernel
 (`tpu_custom_call`). Covered: `window_features` (dataset build),
 `holt_winters` (every Holt-Winters `smooth` on TPU), and `episode_block`
 for every policy the `decide_kernel` auto rule sends to it
-(`Controller.tpu_kernel`).
+(`Controller.tpu_kernel`). Besides, the fleet runner's AAPA
+reclassification is compiled for the CPU and for the v5e and checked to
+hold no loop and no gather.
 
 The topology is described inside a module fixture — never at import —
 so under pytest-xdist only the worker that runs this file loads the TPU
 compiler library; that worker keeps it until it exits.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.kernels import ops
@@ -96,3 +100,35 @@ def test_kernel_policies_are_the_auto_rule_set():
     declared = tuple(n for n in registry.available()
                      if registry.get_controller(n, cfg).tpu_kernel)
     assert declared == KERNEL_POLICIES
+
+
+@pytest.mark.parametrize("platform", ["cpu", "v5e"])
+def test_reclassify_has_no_loop_and_no_gather(platform, request):
+    """The fleet runner at test size, with a classifier of the published
+    shape (60 rounds x 4 classes of depth-4 trees): the optimized
+    program's ops under the `lane.reclassify` scope hold no `while` and
+    no `gather` (the bins' binary search, the tree-chunk loop and the
+    leaf lookup of the host table path)."""
+    from perfbench import classifier
+    from perfbench.tests import fakes
+    from repro.evals import fleet
+    from repro.obs import stages
+    classify = classifier.program_classify(
+        fakes.random_classifier(rounds=60, depth=4))
+    fs = fleet.spec("t", policies=("hpa", "aapa"), n_workloads=16,
+                    w_chunk=8, minutes=30)
+    run = fleet.make_fleet_runner(fs, classify, donate=False)
+    if platform == "cpu":
+        rates = np.zeros((2, 8, 30), np.float32)
+    else:
+        request.getfixturevalue("no_compile_cache")
+        rates = jax.ShapeDtypeStruct((2, 8, 30), jnp.float32,
+                                     sharding=request.getfixturevalue(
+                                         "one_chip"))
+    hlo = run.lower(rates).compile().as_text()
+    ops = [m.group(1) for line in hlo.splitlines()
+           if stages.RECLASSIFY in line
+           for m in [re.search(r"= [^=]*? ([a-z][\w-]*)\(", line)] if m]
+    assert "compare" in ops
+    assert "while" not in ops
+    assert "gather" not in ops
