@@ -44,7 +44,7 @@ from repro.evals import rei as ER
 from repro.evals.matrix import _lane_runner
 from repro.obs.stages import FLEET_FOLD, FLEET_PUT
 from repro.scaling import registry, scenarios
-from repro.sim.cluster import SimConfig
+from repro.sim.cluster import LanePlant, SimConfig
 
 
 @dataclasses.dataclass(frozen=True)
@@ -131,6 +131,15 @@ def build_rates(spec_: FleetSpec) -> np.ndarray:
     return np.stack([chunk_rates(spec_, c) for c in range(spec_.n_chunks)])
 
 
+def _lane_plant(plant: LanePlant | None, axes) -> LanePlant | None:
+    """A per-lane plant as float32 arrays constrained like the rates'
+    lane axes (None stays None: every lane runs the spec's plant)."""
+    if plant is None:
+        return None
+    return LanePlant(*(shd.constrain(jnp.asarray(a, jnp.float32), axes)
+                       for a in plant))
+
+
 def _pooled_acc0(n_lanes: int, bins: int):
     return jax.tree.map(lambda a: jnp.broadcast_to(a, (n_lanes,) + a.shape),
                         EM.accum_init(bins))
@@ -144,6 +153,10 @@ def make_fleet_runner(spec_: FleetSpec, classify=None, *,
     carry; the rates buffer is donated (it is dead after the scan reads
     it). The chunk's lane axis is constrained over "dp".
 
+    ``run(rates, plant)`` gives every workload its own plant parameters:
+    `plant` is a ``LanePlant`` of [C, Wc] arrays, scanned beside the
+    rates chunk by chunk and sharded like them.
+
     With ``spec_.trace_lanes > 0`` the runner returns ``(accum,
     ControlTrace)`` — the trace of K sampled lanes per chunk rides the
     chunk scan as ys (decisions leaves [C, M, H, P, K], minutes
@@ -156,53 +169,64 @@ def make_fleet_runner(spec_: FleetSpec, classify=None, *,
                          telemetry=telemetry,
                          trace_lanes=spec_.trace_lanes or None)
 
-    def run(rates):
+    def run(rates, plant: LanePlant | None = None):
         rates = shd.constrain(jnp.asarray(rates, jnp.float32),
                               (None, "dp", None))
+        plant = _lane_plant(plant, (None, "dp"))
 
-        def body(acc, chunk):
+        def body(acc, xs):
+            chunk, plant_c = xs
             if telemetry:
-                acc_c, ct = lanes(chunk)
+                acc_c, ct = lanes(chunk, plant_c)
                 return jax.tree.map(jnp.add, acc, acc_c), ct
-            return jax.tree.map(jnp.add, acc, lanes(chunk)), None
+            return jax.tree.map(jnp.add, acc, lanes(chunk, plant_c)), None
 
         acc, ct = jax.lax.scan(body,
-                               _pooled_acc0(len(ctrls), spec_.bins), rates)
+                               _pooled_acc0(len(ctrls), spec_.bins),
+                               (rates, plant))
         return (acc, ct) if telemetry else acc
 
     return jax.jit(run, donate_argnums=(0,) if donate else ())
 
 
 def _jit_fold(spec_: FleetSpec, classify=None):
-    """jit with a DONATED accumulator: (MetricAccum [P], rates [Wc, M])
-    -> MetricAccum [P], the device program of `make_chunk_folder`."""
+    """jit with a DONATED accumulator: (MetricAccum [P], rates [Wc, M],
+    plant=None) -> MetricAccum [P], the device program of
+    `make_chunk_folder`."""
     cfg = spec_.sim_config()
     ctrls = controllers(spec_, classify)
     edges = EM.response_edges(spec_.bins, cfg.resp_cap_sec)
     lanes = _lane_runner(ctrls, cfg, edges, per_workload=False)
 
-    def fold(acc, chunk):
+    def fold(acc, chunk, plant: LanePlant | None = None):
         chunk = shd.constrain(jnp.asarray(chunk, jnp.float32), ("dp", None))
-        return jax.tree.map(jnp.add, acc, lanes(chunk))
+        return jax.tree.map(jnp.add, acc,
+                            lanes(chunk, _lane_plant(plant, ("dp",))))
 
     return jax.jit(fold, donate_argnums=(0,))
 
 
 def make_chunk_folder(spec_: FleetSpec, classify=None):
-    """(MetricAccum [P], rates [Wc, M]) -> MetricAccum [P], the
-    streaming fold for generator-fed fleets: host memory is one chunk of
-    rates + one O(P * bins) accumulator, so W is bounded by wall clock,
-    not memory. Each call puts the chunk on the device with the lanes'
-    sharding (the ``fleet.put`` host span) and calls the jitted fold,
-    which DONATES the accumulator (the ``fleet.fold`` span)."""
+    """(MetricAccum [P], rates [Wc, M], plant=None) -> MetricAccum [P],
+    the streaming fold for generator-fed fleets: host memory is one
+    chunk of rates + one O(P * bins) accumulator, so W is bounded by
+    wall clock, not memory. Each call puts the chunk (and the chunk's
+    per-lane plant, a ``LanePlant`` of [Wc] arrays, when given) on the
+    device with the lanes' sharding (the ``fleet.put`` host span) and
+    calls the jitted fold, which DONATES the accumulator (the
+    ``fleet.fold`` span)."""
     jfold = _jit_fold(spec_, classify)
 
-    def folder(acc, chunk):
+    def folder(acc, chunk, plant: LanePlant | None = None):
         with jax.profiler.TraceAnnotation(FLEET_PUT):
             chunk = jax.device_put(
                 chunk, shd.lane_sharding(np.shape(chunk), w_axis=0))
+            if plant is not None:
+                plant = LanePlant(*(jax.device_put(
+                    a, shd.lane_sharding(np.shape(a), w_axis=0))
+                    for a in plant))
         with jax.profiler.TraceAnnotation(FLEET_FOLD):
-            return jfold(acc, chunk)
+            return jfold(acc, chunk, plant)
 
     return folder
 

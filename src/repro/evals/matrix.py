@@ -156,13 +156,17 @@ def build_rates(spec_: MatrixSpec) -> np.ndarray:
 def _lane_runner(ctrls, cfg, edges, *, per_workload: bool = True,
                  shard: bool = True, telemetry: bool = False,
                  trace_lanes: int | None = None):
-    """rates [W, M] -> MetricAccums of [P, W, ...] leaves: ONE blocked
-    scan advances all P x W fused plant lanes with exactly one `decide`
-    per controller per control step (`scaling.batch.make_batch_minute_
-    step`), folding each minute into per-lane MetricAccums in the scan
-    carry — the shared core of the matrix runner, the ad-hoc controller
-    evaluator, and the fleet runner. Memory stays O(bins) per lane. The
-    fold runs under the ``lane.metric_fold`` stage (``repro.obs.stages``).
+    """(rates [W, M], plant=None) -> MetricAccums of [P, W, ...] leaves:
+    ONE blocked scan advances all P x W fused plant lanes with exactly
+    one `decide` per controller per control step
+    (`scaling.batch.make_batch_minute_step`), folding each minute into
+    per-lane MetricAccums in the scan carry — the shared core of the
+    matrix runner, the ad-hoc controller evaluator, and the fleet runner.
+    Memory stays O(bins) per lane. The fold runs under the
+    ``lane.metric_fold`` stage (``repro.obs.stages``).
+    `plant` (optional) is a ``sim.cluster.LanePlant`` of [W] arrays, each
+    workload's own capacity, service time and SLO; without it every lane
+    runs the `cfg` plant.
 
     With ``per_workload=False`` the workload axis reduces *inside* the
     scan (`EM.accum_update_pooled`) and the leaves are [P, ...]: the
@@ -185,7 +189,7 @@ def _lane_runner(ctrls, cfg, edges, *, per_workload: bool = True,
     else:
         fold = lambda a, m: EM.accum_update_pooled(a, m, edges)  # noqa: E731
 
-    def lanes(rates_w):
+    def lanes(rates_w, plant=None):
         W, _ = rates_w.shape
         lead = (n_lanes, W) if per_workload else (n_lanes,)
         acc0 = jax.tree.map(
@@ -208,7 +212,8 @@ def _lane_runner(ctrls, cfg, edges, *, per_workload: bool = True,
 
         (_, _, acc), ct = jax.lax.scan(
             body,
-            (batch.batch_initial_state(ctrls, W, cfg), jnp.int32(0), acc0),
+            (batch.batch_initial_state(ctrls, W, cfg, plant),
+             jnp.int32(0), acc0),
             rates_w.T)
         return (acc, ct) if telemetry else acc
     return lanes

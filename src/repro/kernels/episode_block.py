@@ -30,6 +30,12 @@ grid step (``jax.closure_convert`` is no help here: it hoists traced
 values and deliberately leaves concrete arrays in the closure). Any
 registry controller works unmodified.
 
+The kernel takes the scalar plant: `cfg`'s capacity, service time and
+SLO are compiled into its body, and the controllers observe no per-lane
+``LanePlant``. A fleet whose lanes each have their own plant runs the
+XLA scan; ``cluster.simulate`` and ``make_simulator`` refuse a
+`LanePlant` with the kernel.
+
 The tick math is ``repro.sim.cluster``'s own shape-agnostic helpers
 (`_pop_pipeline`, `_flow_tick`, `_apply_scaling`, `advance_plant`) and
 the shared `apply_decision` limiter — the identical contraction-stable

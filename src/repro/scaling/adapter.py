@@ -11,6 +11,10 @@ Time mapping: serving demos compress time ("one logical minute" of trace
 throughout; `sim_config_for_engine` derives a `SimConfig` whose capacity
 and latency fields describe the engine in those units, so one policy +
 one hyperparameter set behaves consistently across both backends.
+
+The adapter takes the scalar plant: the engine is one endpoint, so its
+`Obs` carries no per-lane ``LanePlant`` and the controllers read
+capacity and service time from that `SimConfig`.
 """
 from __future__ import annotations
 

@@ -36,6 +36,16 @@ class Obs(NamedTuple):
     rate_rps: jax.Array      # current arrival rate (req/s)
     rate_history: jax.Array  # [history_len] per-minute counts (old->new)
     minute_idx: jax.Array    # int32 global minute
+    plant: Any = None        # the lane's own sim.cluster.LanePlant, or
+    #                          None where every lane has the cfg plant
+
+
+def lane_plant(obs: Obs, cfg):
+    """The observed lane's capacity and service time: ``obs.plant`` (its
+    `rps_per_replica`, `service_sec`, `slo_sec`) where each lane has its
+    own, else `cfg`, whose fields carry the same names. A controller
+    converts a rate into replicas with this, never with `cfg` alone."""
+    return cfg if obs.plant is None else obs.plant
 
 
 class Controller(NamedTuple):

@@ -48,7 +48,7 @@ compiled call:
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Sequence
+from typing import Any, NamedTuple, Sequence
 
 import numpy as np
 import jax
@@ -60,17 +60,20 @@ from repro.obs.stages import DECIDE, PLANT, stage
 from repro.scaling import registry
 from repro.scaling.api import (Controller, LimiterState, Obs,
                                apply_decision)
-from repro.sim.cluster import (MinuteOut, SimConfig, advance_plant,
-                               minute_step, simulate, _acc_fold,
-                               _acc_init, _apply_scaling, _flow_tick,
-                               _pop_pipeline, initial_state)
+from repro.sim.cluster import (LanePlant, MinuteOut, SimConfig,
+                               advance_plant, minute_step, simulate,
+                               _acc_fold, _acc_init, _apply_scaling,
+                               _flow_tick, _pop_pipeline, initial_state)
 
 
 class BatchState(NamedTuple):
     """Plant state for P x W fused lanes plus the per-controller control
     states (leaves lead with [W]). W is the fleet/sharding axis: every
     lane field keeps it second so `constrain_lanes` can pin it to the
-    "dp" mesh axis."""
+    "dp" mesh axis. `plant` is each workload's own plant parameters
+    (a `LanePlant` of [W] arrays, shared across policies, read by the
+    plant ticks and by every controller's `decide`), or None where all
+    lanes run the `SimConfig` plant."""
     ready: jax.Array         # [P, W]
     pipeline: jax.Array      # [P, W, startup_sec]
     pipe_sum: jax.Array      # [P, W]
@@ -81,9 +84,11 @@ class BatchState(NamedTuple):
     last_dir: jax.Array      # [P, W]
     rate_history: jax.Array  # [W, history_len] (shared across policies)
     ctrl: tuple              # per-controller state pytrees, leaves [W, ...]
+    plant: Any = None        # LanePlant of [W] arrays, or None
 
 
-def batch_initial_state(ctrls, W: int, cfg: SimConfig) -> BatchState:
+def batch_initial_state(ctrls, W: int, cfg: SimConfig,
+                        plant: LanePlant | None = None) -> BatchState:
     P = len(ctrls)
     st = initial_state(ctrls[0], cfg)
 
@@ -98,19 +103,28 @@ def batch_initial_state(ctrls, W: int, cfg: SimConfig) -> BatchState:
         last_dir=jnp.zeros((P, W), jnp.float32),
         rate_history=jnp.zeros((W, cfg.history_len), jnp.float32),
         ctrl=tuple(jax.vmap(lambda _, c=c: c.init())(jnp.arange(W))
-                   for c in ctrls))
+                   for c in ctrls),
+        plant=plant)
+
+
+#: vmap axes of a batch's Obs over its W lanes: every lane field (the
+#: per-lane plant's too) maps over W, the minute index is shared
+OBS_AXES = Obs(0, 0, 0, 0, 0, 0, None, 0)
 
 
 def constrain_lanes(state: BatchState) -> BatchState:
     """Constrain every lane field's workload axis over the "dp" mesh
     axis (no-op without an active mesh): [P, W, ...] fields shard dim 1,
-    rate_history and the per-controller [W, ...] states shard dim 0."""
+    rate_history, the per-controller [W, ...] states and the per-lane
+    plant's [W] arrays shard dim 0."""
     lanes = {f: shd.constrain(getattr(state, f), (None, "dp"))
              for f in ("ready", "pipeline", "pipe_sum", "queue",
                        "wait_sum", "util_ema", "cooldown", "last_dir")}
     return state._replace(
         rate_history=shd.constrain(state.rate_history, ("dp",)),
         ctrl=jax.tree.map(lambda x: shd.constrain(x, ("dp",)), state.ctrl),
+        plant=jax.tree.map(lambda x: shd.constrain(x, ("dp",)),
+                           state.plant),
         **lanes)
 
 
@@ -123,7 +137,8 @@ def _batch_ctrl_tick(cfg, ctrls, state: BatchState, acc, arr_w,
     batched and single-lane dynamics cannot drift apart. `telemetry`
     (static) additionally returns a [P, W] DecisionRecord; the False
     path is op-for-op the pre-telemetry program. The plant pieces run
-    under the ``lane.plant`` stage, the decides under ``lane.decide``."""
+    under the ``lane.plant`` stage, the decides under ``lane.decide``;
+    both read the lanes' own plant (``state.plant``) where there is one."""
     with stage(PLANT):
         ready, pipeline, pipe_sum = _pop_pipeline(
             state.ready, state.pipeline, state.pipe_sum)
@@ -131,7 +146,7 @@ def _batch_ctrl_tick(cfg, ctrls, state: BatchState, acc, arr_w,
         arr_pw = jnp.broadcast_to(arr_w, ready.shape)
         (queue, wait_sum, util_ema, served, violated, cold, resp,
          util) = _flow_tick(cfg, ready, state.queue, state.wait_sum,
-                            state.util_ema, arr_pw)
+                            state.util_ema, arr_pw, state.plant)
         total = ready + pipe_sum
 
     W = arr_w.shape[0]
@@ -141,18 +156,16 @@ def _batch_ctrl_tick(cfg, ctrls, state: BatchState, acc, arr_w,
             obs = Obs(ready_total=total[p], ready=ready[p],
                       util_ema=util_ema[p], queue=queue[p], rate_rps=arr_w,
                       rate_history=state.rate_history,
-                      minute_idx=minute_idx)
-            cs, des, coo = jax.vmap(
-                c.decide, in_axes=(0, Obs(0, 0, 0, 0, 0, 0, None)))(
-                    state.ctrl[p], obs)
+                      minute_idx=minute_idx, plant=state.plant)
+            cs, des, coo = jax.vmap(c.decide, in_axes=(0, OBS_AXES))(
+                state.ctrl[p], obs)
             new_ctrl.append(cs)
             desired.append(jnp.asarray(des, jnp.float32))
             cool_req.append(jnp.broadcast_to(
                 jnp.asarray(coo, jnp.float32), (W,)))
             if telemetry:
-                exps.append(jax.vmap(
-                    c.explain, in_axes=(0, Obs(0, 0, 0, 0, 0, 0, None)))(
-                        state.ctrl[p], obs)
+                exps.append(jax.vmap(c.explain, in_axes=(0, OBS_AXES))(
+                    state.ctrl[p], obs)
                     if getattr(c, "explain", None) is not None
                     else obs_trace.explain_nan((W,)))
     with stage(PLANT):
@@ -173,7 +186,7 @@ def _batch_ctrl_tick(cfg, ctrls, state: BatchState, acc, arr_w,
                            wait_sum=wait_sum, util_ema=util_ema,
                            cooldown=lim.cooldown, last_dir=lim.last_dir,
                            rate_history=state.rate_history,
-                           ctrl=tuple(new_ctrl))
+                           ctrl=tuple(new_ctrl), plant=state.plant)
         acc = _acc_fold(acc, (served, violated, cold, ready + pipe_sum,
                               resp, util, act.scale_up.astype(jnp.float32),
                               act.scale_down.astype(jnp.float32),
@@ -198,7 +211,7 @@ def _batch_plant_block(cfg, state: BatchState, acc, arr_pw, n_ticks: int):
          cool), acc = advance_plant(
             cfg, state.ready, state.pipeline, state.pipe_sum, state.queue,
             state.wait_sum, state.util_ema, state.cooldown, acc, arr_pw,
-            n_ticks)
+            n_ticks, state.plant)
     state = state._replace(
         ready=ready, pipeline=pipeline, pipe_sum=pipe_sum, queue=queue,
         wait_sum=wait_sum, util_ema=util_ema, cooldown=cool)

@@ -21,6 +21,15 @@ runs compiled inside ``repro.sim.cluster`` and eagerly inside
 ``repro.scaling.adapter``. The `cfg` argument is duck-typed — anything
 with the ``SimConfig`` capacity fields (`rps_per_replica`, `service_sec`,
 `initial_replicas`, `control_interval_sec`) works.
+
+Where a controller converts a rate into replicas (`predictive`, `aapa`,
+`hybrid`) or into concurrency (`kpa`), it reads the lane's capacity and
+service time in `decide` through ``api.lane_plant(obs, cfg)``: the
+observed lane's own `LanePlant` when the plant gives each lane its own
+(``repro.sim.cluster.LanePlant``, carried by ``repro.scaling.batch``),
+else the scalar `cfg`. `hpa` reads utilization only. The serving adapter
+and the fused episode kernel observe no `LanePlant`, so they run the
+scalar plant.
 """
 from __future__ import annotations
 
@@ -38,7 +47,7 @@ from repro.forecast import conformal as fconf
 from repro.forecast import registry as forecast_registry
 from repro.obs.stages import RECLASSIFY, stage
 from repro.obs.trace import ExplainOut
-from repro.scaling.api import Controller, Obs
+from repro.scaling.api import Controller, Obs, lane_plant
 
 EPSF = 1e-9
 
@@ -134,8 +143,9 @@ def predictive_controller(cfg, *, target: float = 0.70,
     def decide(state: PredState, obs: Obs):
         iv = fcst.forecast(state.fc, horizon_min)
         pred_per_min = jnp.maximum(iv.hi if conservative else iv.point, 0.0)
-        need_pred = pred_per_min / 60.0 / (cfg.rps_per_replica * target)
-        need_now = obs.rate_rps / (cfg.rps_per_replica * target)
+        rps = lane_plant(obs, cfg).rps_per_replica
+        need_pred = pred_per_min / 60.0 / (rps * target)
+        need_now = obs.rate_rps / (rps * target)
         desired = jnp.ceil(jnp.maximum(need_pred, need_now))
         # scale to zero when neither live traffic nor forecast needs pods
         idle = ((desired < 1.0) & (obs.queue <= 0.0)
@@ -222,7 +232,8 @@ def aapa_controller(
         return jax.lax.cond(do, reclassify, keep, None)
 
     def decide(state: AAPAState, obs: Obs):
-        cap = cfg.rps_per_replica * jnp.maximum(state.cpu_adj, 0.05)
+        cap = (lane_plant(obs, cfg).rps_per_replica
+               * jnp.maximum(state.cpu_adj, 0.05))
         # reactive component (archetype-specific utilization target)
         ratio = obs.util_ema / jnp.maximum(state.cpu_adj, 0.05)
         reactive = jnp.ceil(obs.ready_total * ratio)
@@ -283,10 +294,12 @@ def kpa_controller(cfg, *, target_concurrency: float | None = None,
     steady-state sizing; when the panic-window estimate needs more than
     `panic_threshold` x the current fleet, the scaler enters panic mode
     for one stable window, during which desired is pinned to the maximum
-    seen (never scales down mid-burst).
+    seen (never scales down mid-burst). The default target is one
+    replica's concurrency at full utilization, the lane's own where the
+    lane has its own plant.
     """
-    if target_concurrency is None:
-        # one replica's concurrency at full utilization
+    lane_target = target_concurrency is None
+    if lane_target:
         target_concurrency = cfg.rps_per_replica * cfg.service_sec
     dt = float(cfg.control_interval_sec)
 
@@ -300,13 +313,16 @@ def kpa_controller(cfg, *, target_concurrency: float | None = None,
         return state
 
     def decide(state: KPAState, obs: Obs):
-        conc = obs.queue + obs.rate_rps * cfg.service_sec
+        lane = lane_plant(obs, cfg)
+        conc = obs.queue + obs.rate_rps * lane.service_sec
         a_s = jnp.float32(min(dt / stable_window_s, 1.0))
         a_p = jnp.float32(min(dt / panic_window_s, 1.0))
         stable = state.stable_ema + a_s * (conc - state.stable_ema)
         panic = state.panic_ema + a_p * (conc - state.panic_ema)
 
         tgt = jnp.float32(target_concurrency)
+        if lane_target and obs.plant is not None:
+            tgt = lane.rps_per_replica * lane.service_sec
         want_stable = jnp.ceil(stable / tgt)
         want_panic = jnp.ceil(panic / tgt)
 
@@ -351,14 +367,18 @@ def hybrid_controller(cfg, classify, *, guard_target: float = 0.85,
     """
     base = aapa_controller(cfg, classify, **aapa_kw)
 
+    def guard_floor(obs: Obs):
+        """Replicas that live utilization and the live rate require."""
+        floor = jnp.ceil(obs.ready_total * obs.util_ema / guard_target)
+        return jnp.maximum(floor,
+                           jnp.ceil(obs.rate_rps
+                                    / (lane_plant(obs, cfg).rps_per_replica
+                                       * guard_target)))
+
     def decide(state, obs: Obs):
         state, desired, cool = base.decide(state, obs)
         # reactive floor from live utilization
-        floor = jnp.ceil(obs.ready_total * obs.util_ema / guard_target)
-        floor = jnp.maximum(floor,
-                            jnp.ceil(obs.rate_rps
-                                     / (cfg.rps_per_replica
-                                        * guard_target)))
+        floor = guard_floor(obs)
         guarded = jnp.maximum(desired, floor)
         # bounded scale-down step
         step_floor = jnp.ceil(obs.ready_total * (1.0 - max_down_frac))
@@ -367,11 +387,6 @@ def hybrid_controller(cfg, classify, *, guard_target: float = 0.85,
         return state, guarded, cool
 
     def explain(state, obs: Obs):
-        floor = jnp.ceil(obs.ready_total * obs.util_ema / guard_target)
-        floor = jnp.maximum(floor,
-                            jnp.ceil(obs.rate_rps
-                                     / (cfg.rps_per_replica
-                                        * guard_target)))
-        return base.explain(state, obs)._replace(guard_floor=floor)
+        return base.explain(state, obs)._replace(guard_floor=guard_floor(obs))
 
     return Controller("hybrid", base.init, base.on_minute, decide, explain)

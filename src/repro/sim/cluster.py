@@ -66,8 +66,8 @@ from repro.obs import trace as obs_trace
 from repro.scaling.api import (Controller, LimiterState, Obs,
                                apply_decision, limiter_init)
 
-__all__ = ["Controller", "Obs", "SimConfig", "SimState", "MinuteOut",
-           "advance_plant", "initial_state", "minute_step",
+__all__ = ["Controller", "Obs", "SimConfig", "LanePlant", "SimState",
+           "MinuteOut", "advance_plant", "initial_state", "minute_step",
            "minute_step_reference", "simulate",
            "simulate_reference", "make_simulator"]
 
@@ -89,6 +89,17 @@ class SimConfig:
     metric_tau_sec: float = 60.0   # 1-minute metric aggregation
     history_len: int = 60          # minutes of rate history kept for ctrl
     resp_cap_sec: float = 600.0    # cap reported response times (metrics)
+
+
+class LanePlant(NamedTuple):
+    """The plant parameters that may differ from lane to lane: each a
+    float32 array that broadcasts against the lane state (a scalar for
+    one lane, [W] for a batch whose state is [W] or [P, W]). Where none
+    is given, every path reads `SimConfig`'s fields of the same names,
+    Python floats that compile as constants, as they always did."""
+    rps_per_replica: Any     # requests per second one ready replica serves
+    service_sec: Any         # per-request service time
+    slo_sec: Any             # response-time SLO threshold
 
 
 class SimState(NamedTuple):
@@ -120,12 +131,15 @@ class MinuteOut(NamedTuple):
     ready_mean: jax.Array
 
 
-def _flow_tick(cfg: SimConfig, ready, queue, wait_sum, util_ema, arrivals):
+def _flow_tick(cfg: SimConfig, ready, queue, wait_sum, util_ema, arrivals,
+               plant: LanePlant | None = None):
     """The queue/response/EMA dynamics of one 1-second tick, after the
     startup-pipeline pop: shared by the control tick, the plant-only
-    tick, the reference tick, and the Pallas kernel oracle."""
+    tick, the reference tick, and the Pallas kernel oracle. Capacity,
+    service time and SLO are the lane's (`plant`), else `cfg`'s."""
+    lane = cfg if plant is None else plant
     # serve FIFO queue (fluid model with queue-age tracking)
-    throughput = ready * cfg.rps_per_replica          # req/s
+    throughput = ready * lane.rps_per_replica         # req/s
     work = queue + arrivals
     served = jnp.minimum(work, throughput)            # dt = 1 s
     new_queue = work - served
@@ -144,12 +158,12 @@ def _flow_tick(cfg: SimConfig, ready, queue, wait_sum, util_ema, arrivals):
     # which LLVM applies per compiled program — the blocked and reference
     # paths compile to different programs, and a contracted-vs-plain resp
     # would break their bitwise parity (div-fed adds cannot contract)
-    resp = (cfg.service_sec / jnp.maximum(1.0 - util, 0.05)
+    resp = (lane.service_sec / jnp.maximum(1.0 - util, 0.05)
             + mean_age
             + (0.5 * new_queue) / jnp.maximum(throughput, EPSF))
     resp = jnp.minimum(resp, cfg.resp_cap_sec)
     resp = jnp.where(served > 0, resp, 0.0)
-    violated = jnp.where(resp > cfg.slo_sec, served, 0.0)
+    violated = jnp.where(resp > lane.slo_sec, served, 0.0)
     cold = jnp.where(ready < 0.5, arrivals, 0.0)      # zero ready pods
     # metrics (util is both the congestion input and the EMA input);
     # div-fed add for the same FMA-stability reason as resp
@@ -192,7 +206,8 @@ def _apply_scaling(ready, pipeline, pipe_sum, act):
 
 def _ctrl_tick(cfg: SimConfig, controller: Controller, state: SimState,
                arrivals: jax.Array, minute_idx: jax.Array, do_ctrl,
-               telemetry: bool = False, head_sec=0.0):
+               telemetry: bool = False, head_sec=0.0,
+               plant: LanePlant | None = None):
     """One 1-second step with a controller decision. `do_ctrl` is the
     Python literal True on block heads (the blocked path — the masking
     folds away) or a traced mask (the reference path, which evaluates
@@ -207,13 +222,14 @@ def _ctrl_tick(cfg: SimConfig, controller: Controller, state: SimState,
     # 2./3. queue + metrics
     (queue, wait_sum, util_ema, served, violated, cold, resp,
      util) = _flow_tick(cfg, ready, state.queue, state.wait_sum,
-                        state.util_ema, arrivals)
+                        state.util_ema, arrivals, plant)
 
     # 4. control every control_interval_sec
     total = ready + pipe_sum
     obs = Obs(ready_total=total, ready=ready, util_ema=util_ema,
               queue=queue, rate_rps=arrivals,
-              rate_history=state.rate_history, minute_idx=minute_idx)
+              rate_history=state.rate_history, minute_idx=minute_idx,
+              plant=plant)
     ctrl_state, desired, cool_req = controller.decide(state.ctrl_state, obs)
     if do_ctrl is not True:
         ctrl_state = jax.tree.map(
@@ -305,11 +321,12 @@ _UNROLL_MAX_TICKS = 16
 
 def advance_plant(cfg: SimConfig, ready, pipeline, pipe_sum, queue,
                   wait_sum, util_ema, cooldown, acc, arrivals,
-                  n_ticks: int):
+                  n_ticks: int, plant: LanePlant | None = None):
     """`n_ticks` decision-free plant ticks with the minute accumulator
     folded along, on one lane or any batch of lanes (shape-agnostic like
     `_pop_pipeline`; the fused P x W batch in ``repro.scaling.batch``
-    calls this on [L] fields). Returns (updated 7-field tuple, acc).
+    calls this on [L] fields). `plant` is the lanes' own parameters
+    (`_flow_tick`). Returns (updated 7-field tuple, acc).
 
     Short blocks (the default 15 s control interval): an unrolled loop
     that reads `pipeline[..., k]` by static index and materializes the
@@ -331,7 +348,7 @@ def advance_plant(cfg: SimConfig, ready, pipeline, pipe_sum, queue,
                                                       pipe_sum)
             (queue, wait_sum, util_ema, served, violated, cold, resp,
              util) = _flow_tick(cfg, ready, queue, wait_sum, util_ema,
-                                arrivals)
+                                arrivals, plant)
             a = _acc_fold_plant(a, served, violated, cold,
                                 ready + pipe_sum, resp, util, ready)
             return (ready, pipeline, pipe_sum, queue, wait_sum, util_ema,
@@ -352,7 +369,7 @@ def advance_plant(cfg: SimConfig, ready, pipeline, pipe_sum, queue,
                 pipe_sum = jnp.maximum(pipe_sum - popped, 0.0)
             (queue, wait_sum, util_ema, served, violated, cold, resp,
              util) = _flow_tick(cfg, ready, queue, wait_sum, util_ema,
-                                arrivals)
+                                arrivals, plant)
             acc = _acc_fold_plant(acc, served, violated, cold,
                                   ready + pipe_sum, resp, util, ready)
         if n_ticks < S:
@@ -368,14 +385,15 @@ def advance_plant(cfg: SimConfig, ready, pipeline, pipe_sum, queue,
 
 
 def _plant_block(cfg: SimConfig, state: SimState, acc,
-                 arrivals: jax.Array, n_ticks: int):
+                 arrivals: jax.Array, n_ticks: int,
+                 plant: LanePlant | None = None):
     """`n_ticks` plant-only ticks folded into the minute accumulator
     (`advance_plant` on the SimState fields)."""
     (ready, pipeline, pipe_sum, queue, wait_sum, util_ema,
      cool), acc = advance_plant(
         cfg, state.ready, state.pipeline, state.pipe_sum, state.queue,
         state.wait_sum, state.util_ema, state.lim.cooldown, acc,
-        arrivals, n_ticks)
+        arrivals, n_ticks, plant)
     state = state._replace(
         ready=ready, pipeline=pipeline, pipe_sum=pipe_sum,
         queue=queue, wait_sum=wait_sum, util_ema=util_ema,
@@ -385,30 +403,32 @@ def _plant_block(cfg: SimConfig, state: SimState, acc,
 
 def _block(cfg: SimConfig, controller: Controller, state: SimState, acc,
            arrivals, minute_idx, n_ticks: int, telemetry: bool = False,
-           head_sec=0.0):
+           head_sec=0.0, plant: LanePlant | None = None):
     """One control period: decide at the head tick, then `n_ticks - 1`
     plant-only ticks, all folded into the minute accumulator."""
     if telemetry:
         state, head, rec = _ctrl_tick(cfg, controller, state, arrivals,
                                       minute_idx, True, telemetry=True,
-                                      head_sec=head_sec)
+                                      head_sec=head_sec, plant=plant)
         acc = _acc_fold(acc, head)
         if n_ticks > 1:
             state, acc = _plant_block(cfg, state, acc, arrivals,
-                                      n_ticks - 1)
+                                      n_ticks - 1, plant)
         return state, acc, rec
     state, head = _ctrl_tick(cfg, controller, state, arrivals, minute_idx,
-                             True)
+                             True, plant=plant)
     acc = _acc_fold(acc, head)
     if n_ticks == 1:
         return state, acc
-    return _plant_block(cfg, state, acc, arrivals, n_ticks - 1)
+    return _plant_block(cfg, state, acc, arrivals, n_ticks - 1, plant)
 
 
 def _minute_blocked(cfg: SimConfig, controller: Controller, carry,
-                    rate_this_min: jax.Array, telemetry: bool = False):
+                    rate_this_min: jax.Array, telemetry: bool = False,
+                    plant: LanePlant | None = None):
     """One minute = ceil(60/ci) control-period blocks + the minute-
     boundary controller hook. `decide` runs exactly once per block.
+    `plant` is the lane's own parameters (`LanePlant`), else `cfg`'s.
 
     With `telemetry` (static flag) the per-minute output becomes
     ``(MinuteOut, ControlTrace)`` where the trace's decisions stack the
@@ -430,7 +450,7 @@ def _minute_blocked(cfg: SimConfig, controller: Controller, carry,
             st, a = carry
             st, a, rec = _block(cfg, controller, st, a, arrivals_per_sec,
                                 minute_idx, ci, telemetry=True,
-                                head_sec=head_sec)
+                                head_sec=head_sec, plant=plant)
             return (st, a), rec
 
         if n_full == 1:
@@ -445,7 +465,8 @@ def _minute_blocked(cfg: SimConfig, controller: Controller, carry,
             state, acc, rec = _block(cfg, controller, state, acc,
                                      arrivals_per_sec, minute_idx, tail,
                                      telemetry=True,
-                                     head_sec=jnp.float32(n_full * ci))
+                                     head_sec=jnp.float32(n_full * ci),
+                                     plant=plant)
             recs.append(jax.tree.map(lambda x: x[None], rec))
         decisions = (recs[0] if len(recs) == 1 else jax.tree.map(
             lambda *xs: jnp.concatenate(xs, axis=0), *recs))  # [H, ...]
@@ -461,17 +482,17 @@ def _minute_blocked(cfg: SimConfig, controller: Controller, carry,
     def block_body(carry, _):
         st, a = carry
         return _block(cfg, controller, st, a, arrivals_per_sec,
-                      minute_idx, ci), None
+                      minute_idx, ci, plant=plant), None
 
     if n_full == 1:      # a length-1 scan only obscures the block body
         state, acc = _block(cfg, controller, state, acc, arrivals_per_sec,
-                            minute_idx, ci)
+                            minute_idx, ci, plant=plant)
     elif n_full:
         (state, acc), _ = jax.lax.scan(block_body, (state, acc), None,
                                        length=n_full)
     if tail:
         state, acc = _block(cfg, controller, state, acc, arrivals_per_sec,
-                            minute_idx, tail)
+                            minute_idx, tail, plant=plant)
     return _finish_minute(cfg, controller, state, minute_idx,
                           rate_this_min, acc)
 
@@ -493,7 +514,8 @@ def _finish_minute(cfg, controller, state, minute_idx, rate_this_min, acc):
 
 # ----------------------------------------------------- reference path ----
 def _minute_reference(cfg: SimConfig, controller: Controller, carry,
-                      rate_this_min: jax.Array):
+                      rate_this_min: jax.Array,
+                      plant: LanePlant | None = None):
     """One minute = 60 ticks (decide evaluated on EVERY tick and masked
     by `do_ctrl` — the historical semantics the blocked scan is pinned
     bit-exact against) + the minute hook."""
@@ -504,7 +526,7 @@ def _minute_reference(cfg: SimConfig, controller: Controller, carry,
         st, a = carry
         do_ctrl = (sec % cfg.control_interval_sec) == 0
         st, out = _ctrl_tick(cfg, controller, st, arrivals_per_sec,
-                             minute_idx, do_ctrl)
+                             minute_idx, do_ctrl, plant=plant)
         return (st, _acc_fold(a, out)), None
 
     (state, acc), _ = jax.lax.scan(tick_body, (state, _acc_init()),
@@ -545,6 +567,13 @@ def use_decide_kernel(explicit: bool | None, *controllers) -> bool:
     return explicit
 
 
+def _reject_decide_kernel_plant():
+    raise ValueError(
+        "a per-lane plant does not compose with decide_kernel: the fused "
+        "episode kernel (repro.kernels.episode_block) compiles the scalar "
+        "SimConfig plant into its body; run with decide_kernel=False")
+
+
 def _reject_decide_kernel_telemetry():
     raise ValueError(
         "telemetry does not compose with decide_kernel: the fused "
@@ -565,7 +594,8 @@ minute_step_reference = _minute_reference
 def simulate(rates_per_min: jax.Array, controller: Controller,
              cfg: SimConfig = SimConfig(), *,
              decide_kernel: bool | None = None,
-             telemetry: bool = False) -> MinuteOut:
+             telemetry: bool = False,
+             plant: LanePlant | None = None) -> MinuteOut:
     """Simulate one workload. rates_per_min [M] -> MinuteOut of [M] arrays.
 
     Control-period-blocked: `decide` runs once per control interval
@@ -580,8 +610,14 @@ def simulate(rates_per_min: jax.Array, controller: Controller,
     leaves [M, H] (H block heads per minute) and minutes leaves [M];
     the default path compiles to the identical pre-telemetry program.
     Incompatible with `decide_kernel` (decisions stay on-chip there).
+
+    `plant` gives the lane its own capacity, service time and SLO
+    (`LanePlant` of scalars); the fused kernel takes only the scalar
+    `cfg` plant, so `plant` with `decide_kernel` raises.
     """
     if use_decide_kernel(decide_kernel, controller):
+        if plant is not None:
+            _reject_decide_kernel_plant()
         if telemetry:
             _reject_decide_kernel_telemetry()
         from repro.kernels import ops
@@ -589,19 +625,22 @@ def simulate(rates_per_min: jax.Array, controller: Controller,
                                 controller, cfg)
         return jax.tree.map(lambda a: a[0], out)
     (state, _), out = jax.lax.scan(
-        partial(_minute_blocked, cfg, controller, telemetry=telemetry),
+        partial(_minute_blocked, cfg, controller, telemetry=telemetry,
+                plant=plant),
         (initial_state(controller, cfg), jnp.int32(0)),
         rates_per_min.astype(jnp.float32))
     return out
 
 
 def simulate_reference(rates_per_min: jax.Array, controller: Controller,
-                       cfg: SimConfig = SimConfig()) -> MinuteOut:
+                       cfg: SimConfig = SimConfig(), *,
+                       plant: LanePlant | None = None) -> MinuteOut:
     """The retained seed-semantics scan (decide evaluated on all 60 ticks
     per minute, masked off-interval). Slow; exists as the parity oracle
-    for `simulate` and the blocked-vs-seed benchmark baseline."""
+    for `simulate` and the blocked-vs-seed benchmark baseline. `plant`
+    gives the lane its own parameters, as in `simulate`."""
     (state, _), out = jax.lax.scan(
-        partial(_minute_reference, cfg, controller),
+        partial(_minute_reference, cfg, controller, plant=plant),
         (initial_state(controller, cfg), jnp.int32(0)),
         rates_per_min.astype(jnp.float32))
     return out
@@ -611,7 +650,9 @@ def make_simulator(controller: Controller, cfg: SimConfig = SimConfig(), *,
                    decide_kernel: bool | None = None,
                    w_chunk: int | None = None, donate: bool = False,
                    telemetry: bool = False):
-    """jit(vmap(simulate)): rates [W, M] -> MinuteOut of [W, M] arrays.
+    """jit(vmap(simulate)): rates [W, M] (and optionally a `LanePlant`
+    of [W] arrays, each lane's own parameters) -> MinuteOut of [W, M]
+    arrays.
 
     Fleet knobs (mirroring `repro.scaling.batch.make_batch_simulator`):
     `w_chunk` scans over chunks of the workload axis inside the one
@@ -628,26 +669,37 @@ def make_simulator(controller: Controller, cfg: SimConfig = SimConfig(), *,
     the kernel's lane tiles, so the vmap disappears and the episode is
     one kernel launch per w-chunk inside the same single compile
     (`_cache_size()` stays 1, pinned in tests/test_decide_kernel.py).
-    Incompatible with `telemetry` (decisions stay on-chip)."""
-    if use_decide_kernel(decide_kernel, controller):
+    Incompatible with `telemetry` (decisions stay on-chip) and with a
+    per-lane plant (the kernel compiles the scalar `cfg` plant)."""
+    use_dk = use_decide_kernel(decide_kernel, controller)
+    if use_dk:
         if telemetry:
             _reject_decide_kernel_telemetry()
         from repro.kernels import ops
-        fn = lambda rates: ops.episode_block(  # noqa: E731
+        fn = lambda rates, _: ops.episode_block(  # noqa: E731
             rates.astype(jnp.float32), controller, cfg)
     else:
-        fn = jax.vmap(lambda r: simulate(r, controller, cfg,
-                                         decide_kernel=False,
-                                         telemetry=telemetry))
+        fn = jax.vmap(lambda r, pl: simulate(r, controller, cfg,
+                                             decide_kernel=False,
+                                             telemetry=telemetry,
+                                             plant=pl))
 
-    def run(rates):
+    def run(rates, plant: LanePlant | None = None):
+        if plant is not None:
+            if use_dk:
+                _reject_decide_kernel_plant()
+            plant = LanePlant(*(jnp.asarray(a, jnp.float32)
+                                for a in plant))
         W, M = rates.shape
         if w_chunk is None or w_chunk >= W:
-            return fn(rates)
+            return fn(rates, plant)
         if W % w_chunk:
             raise ValueError(f"w_chunk {w_chunk} must divide W {W}")
         chunked = rates.reshape(W // w_chunk, w_chunk, M)
-        _, out = jax.lax.scan(lambda c, r: (c, fn(r)), 0, chunked)
+        plant = jax.tree.map(lambda a: a.reshape(W // w_chunk, w_chunk),
+                             plant)
+        _, out = jax.lax.scan(lambda c, xs: (c, fn(*xs)), 0,
+                              (chunked, plant))
         return jax.tree.map(lambda a: a.reshape((W,) + a.shape[2:]), out)
 
     return jax.jit(run, donate_argnums=(0,) if donate else ())
