@@ -78,12 +78,14 @@ def seasonal_naive_forecaster(*, period: int = 60) -> Forecaster:
     cyclic serverless traffic; needs one full period of warm-up)."""
 
     def update(st: SeasonalState, y):
-        return SeasonalState(season=st.season.at[st.t % period].set(y),
+        hot = fc.ring_slot(st.t, period)
+        return SeasonalState(season=fc.ring_write(st.season, hot, y),
                              t=st.t + 1)
 
     def point(st: SeasonalState, h: int):
-        phases = (st.t + jnp.arange(1, h + 1) - 1) % period
-        return jnp.maximum(jnp.max(st.season[phases]), 0.0)
+        _, _, read = fc.ring_lookahead(st.t, period, h)
+        peak = jnp.max(jnp.where(read, st.season, -jnp.inf), axis=-1)
+        return jnp.maximum(peak, 0.0)
 
     return make_forecaster(
         "seasonal_naive",

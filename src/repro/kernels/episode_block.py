@@ -52,8 +52,8 @@ two paths are different XLA programs, so FMA contraction may differ
 On a TPU the body must lower through Mosaic, which takes elementwise
 math, selects and static slices, but no gather, scatter, sort or FFT.
 hpa and kpa qualify and declare it (`Controller.tpu_kernel`, compiled
-for a v5e in tests/test_tpu_compile.py). predictive (Holt-Winters
-seasonal slot by index), aapa and hybrid (sorted and rFFT features,
+for a v5e in tests/test_tpu_compile.py). predictive (its on_minute
+reads `hist[-1]`, a gather), aapa and hybrid (sorted and rFFT features,
 GBDT table gathers) do not, so the auto rule sends them to the XLA
 scan; interpret mode runs every policy.
 """

@@ -159,9 +159,10 @@ def predictive_controller(cfg, *, target: float = 0.70,
                           confidence=_nan(), archetype=_nan(),
                           guard_floor=_nan())
 
-    # tpu_kernel stays False: the Holt-Winters state reads and writes its
-    # seasonal slot by index, a gather/scatter the TPU kernel compiler
-    # does not lower
+    # tpu_kernel stays False: the Holt-Winters ring no longer gathers or
+    # scatters, but `hist[-1]` in on_minute still lowers to a gather the
+    # TPU kernel compiler refuses (read by mask, the episode kernel
+    # compiles for a v5e), and no chip cell times the kernel yet
     return Controller("predictive", init, on_minute, decide, explain)
 
 

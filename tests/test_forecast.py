@@ -12,7 +12,8 @@ from repro.core import uncertainty
 from repro.data.azure_synth import generate_traces
 from repro.forecast import (Forecaster, backtest, conformal,
                             interval_confidence, registry)
-from repro.forecast.api import FState
+from repro.forecast.api import FState, make_forecaster
+from repro.forecast.models import SeasonalState
 from repro.core.archetypes import Archetype
 
 
@@ -172,6 +173,166 @@ def test_hw_smooth_reuses_one_compile_across_series_lengths():
         jnp.float32(0.01), jnp.float32(0.3), period=24))[:, :100]
     np.testing.assert_array_equal(
         np.asarray(fc.hw_smooth(jnp.asarray(y), period=24)), direct)
+
+
+# ------------------------------------------- seasonal ring by slot mask ----
+# The index forms the ring forecasters had before they addressed the ring
+# by slot mask: the oracles the mask forms must equal bit for bit.
+RING_PERIOD = 60
+RING_LANES = 4096
+
+
+def _hw_step_gather(state, y, *, alpha=0.1, beta=0.01, gamma=0.3):
+    period = state.season.shape[0]
+    phase = state.t % period
+    s_t = state.season[phase]
+    level_new = alpha * (y - s_t) + (1.0 - alpha) * (state.level + state.trend)
+    trend_new = beta * (level_new - state.level) + (1.0 - beta) * state.trend
+    season_new = state.season.at[phase].set(
+        gamma * (y - level_new) + (1.0 - gamma) * s_t)
+    return fc.HWState(level_new, trend_new, season_new, state.t + 1)
+
+
+def _hw_forecast_max_gather(state, horizon):
+    hs = jnp.arange(1, horizon + 1)
+    period = state.season.shape[0]
+    phases = (state.t + hs - 1) % period
+    preds = state.level + hs.astype(jnp.float32) * state.trend \
+        + state.season[phases]
+    return jnp.max(preds)
+
+
+def _naive_update_gather(st, y):
+    return st._replace(season=st.season.at[st.t % RING_PERIOD].set(y),
+                       t=st.t + 1)
+
+
+def _naive_point_gather(st, h):
+    phases = (st.t + jnp.arange(1, h + 1) - 1) % RING_PERIOD
+    return jnp.maximum(jnp.max(st.season[phases]), 0.0)
+
+
+def _gather_forecaster(name):
+    if name == "holt_winters":
+        return make_forecaster(
+            "holt_winters_gather", init_inner=None,
+            update_inner=_hw_step_gather,
+            point_fn=lambda st, h: jnp.maximum(
+                _hw_forecast_max_gather(st, h), 0.0))
+    return make_forecaster("seasonal_naive_gather", init_inner=None,
+                           update_inner=_naive_update_gather,
+                           point_fn=_naive_point_gather)
+
+
+def _ring_phases(case, rng):
+    """Per-lane phases `t`: spread over three periods, the ring's two
+    ends, or near the top of int32 (leaving room for 2 * period + 10
+    steps of look-ahead in the index form)."""
+    if case == "spread":
+        return rng.integers(0, 3 * RING_PERIOD, RING_LANES)
+    if case == "ends":
+        return rng.choice([0, RING_PERIOD - 1], RING_LANES)
+    return rng.integers(2**31 - 2**20, 2**31 - 4 * RING_PERIOD, RING_LANES)
+
+
+def _ring_lanes(name, case, seed=0):
+    """A vmapped batch of forecaster states with per-lane phases."""
+    rng = np.random.default_rng(seed)
+    season = rng.normal(0.0, 50.0, (RING_LANES, RING_PERIOD))
+    season[::7] *= 1e-3                       # small and large magnitudes
+    season = jnp.asarray(season, jnp.float32)
+    t = jnp.asarray(_ring_phases(case, rng), jnp.int32)
+    if name == "holt_winters":
+        inner = fc.HWState(
+            level=jnp.asarray(rng.gamma(2.0, 40.0, RING_LANES), jnp.float32),
+            trend=jnp.asarray(rng.normal(0.0, 3.0, RING_LANES), jnp.float32),
+            season=season, t=t)
+    else:
+        inner = SeasonalState(season=season, t=t)
+    resid = jnp.asarray(rng.gamma(1.0, 5.0, RING_LANES), jnp.float32)
+    ys = jnp.asarray(rng.gamma(2.0, 30.0, (3, RING_LANES)), jnp.float32)
+    return FState(inner=inner, resid=resid), ys
+
+
+def _assert_trees_equal(got, want):
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+RING_FORECASTERS = ("holt_winters", "seasonal_naive")
+RING_PHASES = ("spread", "ends", "int32_top")
+RING_HORIZONS = (1, 15, RING_PERIOD, RING_PERIOD + 1, 2 * RING_PERIOD + 10)
+
+
+@pytest.mark.parametrize("case", RING_PHASES)
+@pytest.mark.parametrize("name", RING_FORECASTERS)
+def test_ring_update_equals_the_index_form(name, case):
+    """Three updates of 4096 lanes, each lane at its own phase: the slot
+    mask read and write give the index form's states exactly."""
+    f = registry.make(name, period=RING_PERIOD)
+    oracle = _gather_forecaster(name)
+    st, ys = _ring_lanes(name, case)
+    got, want = st, st
+    for y in ys:
+        got = jax.jit(jax.vmap(f.update))(got, y)
+        want = jax.jit(jax.vmap(oracle.update))(want, y)
+        _assert_trees_equal(got, want)
+
+
+@pytest.mark.parametrize("horizon", RING_HORIZONS)
+@pytest.mark.parametrize("case", RING_PHASES)
+@pytest.mark.parametrize("name", RING_FORECASTERS)
+def test_ring_lookahead_equals_the_index_form(name, case, horizon):
+    """The horizon max by slot mask equals the max over the gathered
+    horizon, for horizons up to and past the period (where a slot is read
+    at several steps), each lane at its own phase."""
+    f = registry.make(name, period=RING_PERIOD)
+    oracle = _gather_forecaster(name)
+    st, _ = _ring_lanes(name, case, seed=horizon)
+    fcast = jax.jit(jax.vmap(lambda s: f.forecast(s, horizon)))
+    want = jax.jit(jax.vmap(lambda s: oracle.forecast(s, horizon)))(st)
+    _assert_trees_equal(fcast(st), want)
+
+
+@pytest.mark.parametrize("horizon", (1, 15, RING_PERIOD + 1))
+def test_hw_forecast_reads_the_slot_of_its_horizon(horizon):
+    """The single-horizon forecast (``hw_smooth``'s one-step prediction)
+    by slot mask equals the index read of slot ``(t + h - 1) % period``."""
+    st, _ = _ring_lanes("holt_winters", "spread", seed=7)
+    hw = st.inner
+    got = jax.vmap(lambda s: fc.hw_forecast(s, horizon))(hw)
+    phase = (hw.t + horizon - 1) % RING_PERIOD
+    want = hw.level + horizon * hw.trend \
+        + jnp.take_along_axis(hw.season, phase[:, None], axis=1)[:, 0]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _primitives(jaxpr):
+    """Every primitive name in a jaxpr, nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _primitives(inner)
+
+
+@pytest.mark.parametrize("method", ("update", "forecast"))
+@pytest.mark.parametrize("name", RING_FORECASTERS)
+def test_ring_forecasters_lower_without_gather_or_scatter(name, method):
+    """Vmapped over lanes with per-lane phases, neither ring forecaster's
+    update nor its 15-minute forecast holds a gather or a scatter: what
+    makes the lane scan's forecast stage cheap on every platform."""
+    f = registry.make(name, period=RING_PERIOD)
+    st, ys = _ring_lanes(name, "spread")
+    if method == "update":
+        jaxpr = jax.make_jaxpr(jax.vmap(f.update))(st, ys[0])
+    else:
+        jaxpr = jax.make_jaxpr(jax.vmap(lambda s: f.forecast(s, 15)))(st)
+    prims = set(_primitives(jaxpr.jaxpr))
+    assert not {p for p in prims if "gather" in p or "scatter" in p}, prims
+    assert "select_n" in prims
 
 
 # -------------------------------------------------------------- conformal ----

@@ -9,7 +9,8 @@ Each test asserts the compiled program holds the kernel
 for every policy the `decide_kernel` auto rule sends to it
 (`Controller.tpu_kernel`). Besides, the fleet runner's AAPA
 reclassification is compiled for the CPU and for the v5e and checked to
-hold no loop and no gather.
+hold no loop and no gather, and its forecast stage to hold no gather and
+no scatter.
 
 The topology is described inside a module fixture — never at import —
 so under pytest-xdist only the worker that runs this file loads the TPU
@@ -132,3 +133,32 @@ def test_reclassify_has_no_loop_and_no_gather(platform, request):
     assert "compare" in ops
     assert "while" not in ops
     assert "gather" not in ops
+
+
+@pytest.mark.parametrize("platform", ["cpu", "v5e"])
+def test_forecast_stage_has_no_gather_or_scatter(platform, request):
+    """The fleet runner at test size (``hpa`` + ``aapa``, Holt-Winters
+    with per-lane phases): the optimized program's ops under the
+    `lane.forecast` scope address the seasonal ring by slot mask, so
+    they hold no `gather` and no `scatter`."""
+    from perfbench import classifier
+    from perfbench.tests import fakes
+    from repro.evals import fleet
+    from repro.obs import stages
+    classify = classifier.program_classify(fakes.random_classifier())
+    fs = fleet.spec("t", policies=("hpa", "aapa"), n_workloads=16,
+                    w_chunk=8, minutes=30)
+    run = fleet.make_fleet_runner(fs, classify, donate=False)
+    if platform == "cpu":
+        rates = np.zeros((2, 8, 30), np.float32)
+    else:
+        request.getfixturevalue("no_compile_cache")
+        rates = jax.ShapeDtypeStruct((2, 8, 30), jnp.float32,
+                                     sharding=request.getfixturevalue(
+                                         "one_chip"))
+    hlo = run.lower(rates).compile().as_text()
+    ops = [m.group(1) for line in hlo.splitlines()
+           if stages.FORECAST in line
+           for m in [re.search(r"= [^=]*? ([a-z][\w-]*)\(", line)] if m]
+    assert "select" in ops
+    assert not [op for op in ops if "gather" in op or "scatter" in op]
