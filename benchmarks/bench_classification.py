@@ -67,7 +67,7 @@ def main():
           "rounds": int(trained.params.feat.shape[0]),
           "depth": int(trained.params.depth),
           "tables_us": tt, "scan_us": ts, "tables_speedup": ts / tt}
-    common.emit("classification_gbdt_tables", tt,
+    common.emit("classification_node_tables", tt,
                 f"tables_vs_scan={ts / tt:.2f}x", gp)
 
 

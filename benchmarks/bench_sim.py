@@ -1,7 +1,7 @@
 """Simulation-plant throughput (the perf trajectory for the ROADMAP's
 "fast as the hardware allows" north star).
 
-Four measurements, all in simulated workload-minutes per wall-second:
+Three measurements, all in simulated workload-minutes per wall-second:
 
 * `sim_blocked`  — the control-period-blocked scan vs the SEED tick-level
   scan (decide evaluated on all 60 ticks/minute, per-tick pipeline
@@ -12,13 +12,6 @@ Four measurements, all in simulated workload-minutes per wall-second:
 * `sim_batch`    — the O(P) per-controller-lane batch vs the seed's
   stacked O(P^2) design (every lane evaluates all P decides) at P = 1..5.
 * `sim_workloads`— blocked-scan scaling in the workload axis.
-* `sim_fused_decide` — the kernel-path trajectory for the policies the
-  `decide_kernel` auto rule sends to the episode kernel on TPU (hpa and
-  kpa, `Controller.tpu_kernel`; hpa leads the record): the whole-episode
-  fused-decide kernel vs the block-head blocked scan vs the tick-level
-  reference. The kernel follows the backend: compiled by Mosaic on a
-  TPU, the Pallas interpreter elsewhere (a correctness vehicle, not a
-  speed claim).
 
 `python -m benchmarks.run sim --json .` writes the records to
 BENCH_sim.json (stable schema) so perf regressions diff across PRs.
@@ -32,7 +25,6 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks import common
-from repro.kernels import ops as kops
 from repro.scaling import batch, registry
 from repro.scaling.api import Obs, apply_decision
 from repro.sim.cluster import (SimConfig, initial_state, simulate,
@@ -256,43 +248,6 @@ def main(smoke: bool = False):
     common.emit("sim_workloads",
                 1e6 / ws["per_w"][top]["minutes_per_sec"],
                 f"w{top}_mps={ws['per_w'][top]['minutes_per_sec']:,.0f}", ws)
-
-    # ---- fused-decide episode kernel trajectory, per policy ------------
-    # ci=30 keeps the unrolled-tick jaxpr small enough that the interpret
-    # kernel compiles in seconds per policy (the TPU path is agnostic).
-    # Only the policies whose decide lowers on TPU (`tpu_kernel`).
-    dk_cfg = SimConfig(control_interval_sec=30)
-    dk_names = tuple(n for n in registry.available()
-                     if registry.get_controller(n, dk_cfg).tpu_kernel)
-    dk_names = dk_names[:1] if smoke else dk_names
-    dk_M = 24 if smoke else 60
-    dk_rates = rates[:, :dk_M]
-    interpret = jax.default_backend() != "tpu"
-    dk = {"workloads": W, "minutes": dk_M, "interpret_mode": interpret,
-          "control_interval_sec": dk_cfg.control_interval_sec,
-          "note": "interpret mode (off the TPU) validates the fused-"
-                  "decide episode kernel; only a TPU number is a speed",
-          "policies": {}}
-    for name in dk_names:
-        ctrl = registry.get_controller(name, dk_cfg)
-        t = _interleaved({
-            "fused_decide": jax.jit(
-                lambda r, c=ctrl: kops.episode_block(r, c, dk_cfg)),
-            "block_head": jax.jit(jax.vmap(
-                lambda r, c=ctrl: simulate(r, c, dk_cfg,
-                                           decide_kernel=False))),
-            "reference": jax.jit(jax.vmap(
-                lambda r, c=ctrl: simulate_reference(r, c, dk_cfg))),
-        }, dk_rates, iters)
-        dk["policies"][name] = {
-            "minutes_per_sec": {k: W * dk_M / v for k, v in t.items()},
-            "fused_over_block_head": t["block_head"] / t["fused_decide"]}
-    lead = dk_names[0]
-    lead_mps = dk["policies"][lead]["minutes_per_sec"]["fused_decide"]
-    common.emit(
-        "sim_fused_decide", 1e6 / lead_mps,
-        f"{lead}_fused_vs_blocked="
-        f"{dk['policies'][lead]['fused_over_block_head']:.3f}x", dk)
 
 
 if __name__ == "__main__":

@@ -19,7 +19,7 @@ Phases (one process, no CPU fallback):
 5. the Table IV matrix of the quickstart (five policies,
    ``archetype_mix``, 1440 minutes, 16 workloads);
 6. single episodes (``make_simulator`` / ``simulate``) per registry
-   policy, on whatever path the ``decide_kernel`` auto rule picks.
+   policy.
 
 Every phase checks its result on the device against the repository's
 own references at the tier-1 tests' tolerances and raises on a
@@ -68,7 +68,7 @@ BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # the tolerances of the tier-1 tests that pin the same comparisons
 FEATURE_TOL = dict(rtol=5e-4, atol=5e-4)       # test_kernel_smoke
 HW_TOL = dict(rtol=1e-4, atol=1e-3)            # test_kernel_smoke
-EPISODE_TOL = dict(rtol=1e-4, atol=1e-3)       # test_decide_kernel, TPU
+EPISODE_TOL = dict(rtol=1e-4, atol=1e-3)       # test_fleet, TPU
 METRIC_RTOL = 2e-4                             # test_evals
 DP_RTOL = 2e-6                                 # test_fleet, 8-device pin
 
@@ -240,9 +240,8 @@ def phase_matrix(classify, classifier_id: str, *, n_workloads: int = 16,
 
 def phase_episodes(classify, *, n_workloads: int = 1000,
                    minutes: int = 240):
-    """Every registry policy through `make_simulator` and `simulate` on
-    the auto rule's path; each policy that can take the episode kernel
-    is checked against `decide_kernel=False`."""
+    """Every registry policy through `make_simulator` and `simulate`:
+    finite outputs, and lane 0 of the fleet equals the lone episode."""
     cfg = cluster.SimConfig()
     rates = jnp.asarray(scenarios.get(
         "burst_storm", n_workloads=n_workloads, minutes=minutes,
@@ -250,26 +249,14 @@ def phase_episodes(classify, *, n_workloads: int = 1000,
     info = {}
     for name in registry.available():
         ctrl = registry.get_controller(name, cfg, classify=classify)
-        on_kernel = cluster.use_decide_kernel(None, ctrl)
         out = cluster.make_simulator(ctrl, cfg)(rates)
         one = cluster.simulate(rates[0], ctrl, cfg)
         for f, a in zip(cluster.MinuteOut._fields, out):
             if not np.all(np.isfinite(np.asarray(a))):
                 raise AssertionError(f"{name}: non-finite {f}")
-        check_close(f"{name} simulate vs make_simulator lane 0",
-                    one.served, out.served[0], **EPISODE_TOL)
-        err = None
-        if ctrl.tpu_kernel:
-            fused = out if on_kernel else cluster.make_simulator(
-                ctrl, cfg, decide_kernel=True)(rates)
-            ref = cluster.make_simulator(ctrl, cfg,
-                                         decide_kernel=False)(rates)
-            err = max(check_close(f"{name} MinuteOut.{f}", a, e,
-                                  **EPISODE_TOL)
-                      for f, a, e in zip(cluster.MinuteOut._fields,
-                                         fused, ref))
-        info[name] = {"path": "episode_kernel" if on_kernel else "xla_scan",
-                      "kernel_vs_scan_max_abs_err": err}
+        info[name] = {"lane0_max_abs_err": check_close(
+            f"{name} simulate vs make_simulator lane 0",
+            one.served, out.served[0], **EPISODE_TOL)}
     return info
 
 

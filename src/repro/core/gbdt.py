@@ -15,9 +15,7 @@ Prediction traverses flattened *node tables*: at fit/load time the
 in lockstep — one static-pattern column gather evaluates every
 (tree, node) split comparison at once, then the level walk is pure
 vector selects (``_descend``), no per-row dynamic gathers and no scan
-over rounds. That is the identical layout and math the Pallas kernel in
-``repro.kernels.gbdt_tables`` streams through VMEM (bit-exact by
-construction); the host path additionally cache-blocks the tree axis
+over rounds. The host path additionally cache-blocks the tree axis
 (``traverse_tables_chunked``, bit-identical — trees are independent).
 The retained per-round scan (``predict_logits_scan``) is the parity
 oracle; the two differ only in logit summation order (reshape-sum vs
@@ -68,8 +66,7 @@ def _leaf_ids(bits: jax.Array) -> jax.Array:
     """bits [N, T, I] per-node go-right decisions -> level-local leaf
     ids [N, T]. The walk is pure vector selects: at depth d the live
     node id picks this level's decision bit through a <= 2^d-way
-    `jnp.where` chain — no lane-dynamic gather, which is exactly the
-    form the Pallas node-table kernel vectorizes."""
+    `jnp.where` chain — no lane-dynamic gather."""
     N, T, I = bits.shape
     depth = (I + 1).bit_length() - 1
     node = jnp.zeros((N, T), jnp.int32)
@@ -97,7 +94,7 @@ def traverse_tables(tables: NodeTables, xb: jax.Array) -> jax.Array:
     (`jnp.take(xb, feat.reshape(-1), axis=1)` — the index vector is
     shared by all rows, so XLA lowers it as a column permutation, not a
     per-row gather), then `_descend` walks the levels with vector
-    selects. This lockstep form is what the kernel executes verbatim."""
+    selects."""
     N = xb.shape[0]
     T, I = tables.feat.shape
     xv = jnp.take(xb, tables.feat.reshape(-1), axis=1)   # [N, T*I]
@@ -174,8 +171,8 @@ class GBDTParams:
     tables:      flattened NodeTables over the round-major tree axis —
                  derived from feat/thresh/leaf exactly once at
                  construction (fit / load / npz restore all route through
-                 here), so neither the host `predict_logits` nor the
-                 Pallas kernel pays the reshape per call.
+                 here), so `predict_logits` does not pay the reshape
+                 per call.
     """
 
     feat: jax.Array
@@ -394,7 +391,7 @@ def row_logits(params: GBDTParams, x: jax.Array) -> jax.Array:
 @jax.jit
 def predict_logits_scan(params: GBDTParams, X: jax.Array) -> jax.Array:
     """The retained per-round scan (one `apply_tree` walk per round):
-    the parity oracle for the table path and the kernel, and the host
+    the parity oracle for the table path, and the host
     baseline bench_classification measures the table speedup against."""
     xb = bin_features(X.astype(jnp.float32), params.bin_edges)
     N = X.shape[0]

@@ -21,21 +21,3 @@ def holt_winters_ref(y: jax.Array, *, period: int = 60, alpha: float = 0.1,
                      beta: float = 0.01, gamma: float = 0.3) -> jax.Array:
     """[B, T] -> one-step-ahead forecasts [B, T]."""
     return hw_smooth(y, period=period, alpha=alpha, beta=beta, gamma=gamma)
-
-
-def episode_block_ref(rates, controller, cfg):
-    """rates [B, M] -> MinuteOut of [B, M]: the CPU blocked scan, one
-    lane per workload — the dispatch oracle for the fused-decide episode
-    kernel (compiled-program parity is ulp-tight, not bitwise; see the
-    episode_block module docstring)."""
-    from repro.sim.cluster import simulate
-    return jax.vmap(lambda r: simulate(r, controller, cfg,
-                                       decide_kernel=False))(rates)
-
-
-def gbdt_logits_ref(params, X):
-    """Host node-table inference — the oracle for the GBDT kernel (the
-    kernel runs the identical traversal over the identical layout, so
-    parity is bit-exact in interpret mode)."""
-    from repro.core.gbdt import predict_logits
-    return predict_logits(params, X)

@@ -61,12 +61,6 @@ class Controller(NamedTuple):
     # repro.obs.trace.ExplainOut — the forecast/confidence/guardrail
     # signals behind the decision `decide` is about to make. Pure and
     # jittable like decide; None means "no signals" (NaN-filled record).
-    tpu_kernel: bool = False
-    # True when init/on_minute/decide lower inside the fused Pallas
-    # episode kernel on TPU: elementwise math only — no gather, scatter,
-    # sort or FFT (pinned by tests/test_tpu_compile.py). The
-    # `decide_kernel` auto rule (repro.sim.cluster.use_decide_kernel)
-    # reads it; a controller that leaves it False runs the XLA scan.
 
 
 # ----------------------------------------------- cooldown / stabilization ----

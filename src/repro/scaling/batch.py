@@ -341,20 +341,12 @@ def make_batch_minute_step(controllers: Sequence[Controller],
 
 def make_batch_simulator(controllers: Sequence[Controller],
                          cfg: SimConfig = SimConfig(), *,
-                         decide_kernel: bool | None = None,
                          shard: bool = True, w_chunk: int | None = None,
                          donate: bool = False, telemetry: bool = False,
                          trace_lanes: int | None = None):
     """jit: rates [W, M] -> MinuteOut [P, W, M]. One compile, one
     dispatch: a single blocked scan over fused P x W plant lanes with
     exactly P (not P^2) decide evaluations per control step.
-
-    `decide_kernel` (auto on TPU when every controller declares
-    `tpu_kernel`, ``cluster.use_decide_kernel``) instead runs one
-    fused-decide episode kernel per controller over the W lanes — every
-    policy's whole episode on-chip (``repro.kernels.episode_block``),
-    stacked back to [P, W, M], still one compile. The off path is the unchanged fused
-    P x W scan. Incompatible with `telemetry` (decisions stay on-chip).
 
     `w_chunk` scans over chunks of the workload axis inside the same
     dispatch, so the live plant state is [P, w_chunk] however large W
@@ -373,22 +365,13 @@ def make_batch_simulator(controllers: Sequence[Controller],
             "telemetry does not compose with w_chunk here; for chunked "
             "capture use repro.evals.fleet with trace_lanes "
             "(FleetSpec(..., trace_lanes=K) samples K lanes per chunk)")
-    from repro.sim.cluster import (_reject_decide_kernel_telemetry,
-                                   use_decide_kernel)
     ctrls = list(controllers)
-    use_dk = use_decide_kernel(decide_kernel, *ctrls)
-    if use_dk and telemetry:
-        _reject_decide_kernel_telemetry()
     step = make_batch_minute_step(ctrls, cfg, shard=shard,
                                   telemetry=telemetry,
                                   trace_lanes=trace_lanes)
 
     def episode(rates):                       # [Wc, M] -> [P, Wc, M]
         W, M = rates.shape
-        if use_dk:
-            from repro.kernels import ops
-            outs = [ops.episode_block(rates, c, cfg) for c in ctrls]
-            return jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
 
         def minute(carry, rate_w):
             state, idx = carry

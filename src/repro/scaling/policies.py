@@ -28,8 +28,7 @@ service time in `decide` through ``api.lane_plant(obs, cfg)``: the
 observed lane's own `LanePlant` when the plant gives each lane its own
 (``repro.sim.cluster.LanePlant``, carried by ``repro.scaling.batch``),
 else the scalar `cfg`. `hpa` reads utilization only. The serving adapter
-and the fused episode kernel observe no `LanePlant`, so they run the
-scalar plant.
+observes no `LanePlant`, so it runs the scalar plant.
 """
 from __future__ import annotations
 
@@ -59,8 +58,7 @@ def _nan() -> jax.Array:
 def _select4(idx, v0, v1, v2, v3):
     """Branch-free 4-way archetype select, bit-exact with ``table[idx]``
     (it returns exactly one of the four values) but lowered as three
-    vector selects instead of a lane-dynamic gather — the form the fused
-    episode kernel (``repro.kernels.episode_block``) vectorizes."""
+    vector selects instead of a lane-dynamic gather."""
     return jnp.where(idx == 0, v0,
                      jnp.where(idx == 1, v1,
                                jnp.where(idx == 2, v2, v3)))
@@ -106,7 +104,7 @@ def hpa_controller(cfg, *, target: float = 0.70,
         return (HPAState(buf, desired), desired,
                 jnp.float32(cooldown_min * 60.0))
 
-    return Controller("hpa", init, on_minute, decide, tpu_kernel=True)
+    return Controller("hpa", init, on_minute, decide)
 
 
 # --------------------------------------------------- Generic Predictive ----
@@ -159,10 +157,6 @@ def predictive_controller(cfg, *, target: float = 0.70,
                           confidence=_nan(), archetype=_nan(),
                           guard_floor=_nan())
 
-    # tpu_kernel stays False: the Holt-Winters ring no longer gathers or
-    # scatters, but `hist[-1]` in on_minute still lowers to a gather the
-    # TPU kernel compiler refuses (read by mask, the episode kernel
-    # compiles for a v5e), and no chip cell times the kernel yet
     return Controller("predictive", init, on_minute, decide, explain)
 
 
@@ -269,9 +263,6 @@ def aapa_controller(
                           archetype=state.arch.astype(jnp.float32),
                           guard_floor=_nan())
 
-    # tpu_kernel stays False: reclassification sorts and runs an rFFT
-    # (core.features) and the GBDT walks its node tables by gather, none
-    # of which the TPU kernel compiler lowers (ROADMAP: DFT on the MXU)
     return Controller("aapa", init, on_minute, decide, explain)
 
 
@@ -346,7 +337,7 @@ def kpa_controller(cfg, *, target_concurrency: float | None = None,
         return (KPAState(stable, panic, panic_left, panic_max), desired,
                 jnp.float32(cooldown_min * 60.0))
 
-    return Controller("kpa", init, on_minute, decide, tpu_kernel=True)
+    return Controller("kpa", init, on_minute, decide)
 
 
 # ---------------------------------------------------------------- hybrid ----

@@ -39,12 +39,6 @@ Two plant-cost levers keep the blocked path hot-loop cheap:
   update sequence in both paths), so plant ticks do O(1) work instead of
   an O(startup_sec) shift + reduction per tick.
 
-On TPU a whole episode of a `tpu_kernel` controller dispatches to the
-fused Pallas kernel ``repro.kernels.episode_block`` (plant ticks and
-`decide` in VMEM); the blocked path below *is* the reference oracle that
-kernel is pinned against — the same kernel/ref dual-dispatch pattern as
-`window_features` and `holt_winters`.
-
 This module is the *plant*; the control plane lives in `repro.scaling`:
 the Controller/Obs protocol and the cooldown semantics come from
 `repro.scaling.api` (re-exported here for back-compat), the policies from
@@ -135,8 +129,8 @@ def _flow_tick(cfg: SimConfig, ready, queue, wait_sum, util_ema, arrivals,
                plant: LanePlant | None = None):
     """The queue/response/EMA dynamics of one 1-second tick, after the
     startup-pipeline pop: shared by the control tick, the plant-only
-    tick, the reference tick, and the Pallas kernel oracle. Capacity,
-    service time and SLO are the lane's (`plant`), else `cfg`'s."""
+    tick and the reference tick. Capacity, service time and SLO are the
+    lane's (`plant`), else `cfg`'s."""
     lane = cfg if plant is None else plant
     # serve FIFO queue (fluid model with queue-age tracking)
     throughput = ready * lane.rps_per_replica         # req/s
@@ -189,8 +183,7 @@ def _apply_scaling(ready, pipeline, pipe_sum, act):
     pipeline tail; removals cancel starting pods first (proportional
     rescale), then ready pods. Shape-agnostic like `_pop_pipeline`."""
     # a static-slot add written as a concatenate, not `.at[..., -1].add`:
-    # the same float op, without the scatter the TPU kernel compiler
-    # (kernels/episode_block.py) cannot lower
+    # the same float op, without a scatter
     pipeline = jnp.concatenate(
         [pipeline[..., :-1], pipeline[..., -1:] + act.add[..., None]],
         axis=-1)
@@ -551,37 +544,6 @@ def initial_state(controller: Controller,
         ctrl_state=controller.init())
 
 
-def use_decide_kernel(explicit: bool | None, *controllers) -> bool:
-    """Dispatch for the fused-decide episode kernel
-    (``repro.kernels.episode_block``). `explicit` True/False forces it;
-    None (auto) takes the kernel on TPU only when every controller
-    declares `tpu_kernel` (its decide lowers there — hpa and kpa), and
-    the blocked scan below (the kernel's oracle) everywhere else. So on
-    TPU predictive / aapa / hybrid run the compiled XLA scan, and on CPU
-    every policy does. The off path is the unmodified blocked scan, so
-    `decide_kernel=False` is bit-exact with not passing the flag at all
-    on CPU (pinned in tests/test_decide_kernel.py)."""
-    if explicit is None:
-        return (jax.default_backend() == "tpu"
-                and all(c.tpu_kernel for c in controllers))
-    return explicit
-
-
-def _reject_decide_kernel_plant():
-    raise ValueError(
-        "a per-lane plant does not compose with decide_kernel: the fused "
-        "episode kernel (repro.kernels.episode_block) compiles the scalar "
-        "SimConfig plant into its body; run with decide_kernel=False")
-
-
-def _reject_decide_kernel_telemetry():
-    raise ValueError(
-        "telemetry does not compose with decide_kernel: the fused "
-        "episode kernel keeps decisions on-chip and never materializes "
-        "DecisionRecords; run with decide_kernel=False, or capture "
-        "sampled lanes via repro.evals.fleet (FleetSpec.trace_lanes)")
-
-
 #: Public minute-granularity step: carry=(SimState, minute_idx) -> per-
 #: minute MinuteOut scalars. `repro.evals.metrics` scans this directly to
 #: accumulate metrics in-carry without materializing [M] outputs. This is
@@ -593,37 +555,21 @@ minute_step_reference = _minute_reference
 
 def simulate(rates_per_min: jax.Array, controller: Controller,
              cfg: SimConfig = SimConfig(), *,
-             decide_kernel: bool | None = None,
              telemetry: bool = False,
              plant: LanePlant | None = None) -> MinuteOut:
     """Simulate one workload. rates_per_min [M] -> MinuteOut of [M] arrays.
 
     Control-period-blocked: `decide` runs once per control interval
     (bit-exact with `simulate_reference`, which evaluates it every tick).
-    `decide_kernel=None` auto-selects the *whole-episode* fused kernel
-    (``repro.kernels.episode_block``) on TPU for a controller that
-    declares `tpu_kernel` (see `use_decide_kernel`) — plant ticks and
-    `decide` both on-chip, this blocked scan as its dispatch oracle.
 
     `telemetry=True` (static) additionally captures the in-scan decision
     trace and returns ``(MinuteOut, ControlTrace)`` with decisions
     leaves [M, H] (H block heads per minute) and minutes leaves [M];
     the default path compiles to the identical pre-telemetry program.
-    Incompatible with `decide_kernel` (decisions stay on-chip there).
 
     `plant` gives the lane its own capacity, service time and SLO
-    (`LanePlant` of scalars); the fused kernel takes only the scalar
-    `cfg` plant, so `plant` with `decide_kernel` raises.
+    (`LanePlant` of scalars).
     """
-    if use_decide_kernel(decide_kernel, controller):
-        if plant is not None:
-            _reject_decide_kernel_plant()
-        if telemetry:
-            _reject_decide_kernel_telemetry()
-        from repro.kernels import ops
-        out = ops.episode_block(rates_per_min.astype(jnp.float32)[None],
-                                controller, cfg)
-        return jax.tree.map(lambda a: a[0], out)
     (state, _), out = jax.lax.scan(
         partial(_minute_blocked, cfg, controller, telemetry=telemetry,
                 plant=plant),
@@ -647,7 +593,6 @@ def simulate_reference(rates_per_min: jax.Array, controller: Controller,
 
 
 def make_simulator(controller: Controller, cfg: SimConfig = SimConfig(), *,
-                   decide_kernel: bool | None = None,
                    w_chunk: int | None = None, donate: bool = False,
                    telemetry: bool = False):
     """jit(vmap(simulate)): rates [W, M] (and optionally a `LanePlant`
@@ -661,33 +606,12 @@ def make_simulator(controller: Controller, cfg: SimConfig = SimConfig(), *,
     `donate` donates the rates buffer to the call, so a fleet-sized
     input tensor never double-buffers against the outputs. `telemetry`
     returns ``(MinuteOut [W, M], ControlTrace)`` with decisions leaves
-    [W, M, H] and minutes leaves [W, M].
-
-    `decide_kernel` (auto on TPU for a `tpu_kernel` controller, see
-    `use_decide_kernel`) routes whole episodes through the fused-decide
-    Pallas kernel — the W lanes ARE
-    the kernel's lane tiles, so the vmap disappears and the episode is
-    one kernel launch per w-chunk inside the same single compile
-    (`_cache_size()` stays 1, pinned in tests/test_decide_kernel.py).
-    Incompatible with `telemetry` (decisions stay on-chip) and with a
-    per-lane plant (the kernel compiles the scalar `cfg` plant)."""
-    use_dk = use_decide_kernel(decide_kernel, controller)
-    if use_dk:
-        if telemetry:
-            _reject_decide_kernel_telemetry()
-        from repro.kernels import ops
-        fn = lambda rates, _: ops.episode_block(  # noqa: E731
-            rates.astype(jnp.float32), controller, cfg)
-    else:
-        fn = jax.vmap(lambda r, pl: simulate(r, controller, cfg,
-                                             decide_kernel=False,
-                                             telemetry=telemetry,
-                                             plant=pl))
+    [W, M, H] and minutes leaves [W, M]."""
+    fn = jax.vmap(lambda r, pl: simulate(r, controller, cfg,
+                                         telemetry=telemetry, plant=pl))
 
     def run(rates, plant: LanePlant | None = None):
         if plant is not None:
-            if use_dk:
-                _reject_decide_kernel_plant()
             plant = LanePlant(*(jnp.asarray(a, jnp.float32)
                                 for a in plant))
         W, M = rates.shape

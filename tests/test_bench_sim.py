@@ -27,15 +27,12 @@ def _check_doc(doc, *, smoke):
     assert doc["bench"] == "sim" and doc["smoke"] is smoke
     assert not doc["failed"]
     names = [r["name"] for r in doc["records"]]
-    assert names == ["sim_blocked", "sim_batch", "sim_workloads",
-                     "sim_fused_decide"]
+    assert names == ["sim_blocked", "sim_batch", "sim_workloads"]
     for r in doc["records"]:
         assert set(r) == {"name", "us_per_call", "derived"}
         assert r["us_per_call"] > 0
     blocked = doc["records"][0]
     assert blocked["derived"].startswith("aapa_blocked_speedup=")
-    fused = doc["records"][3]
-    assert fused["derived"].startswith("hpa_fused_vs_blocked=")
 
 
 @pytest.mark.slow
@@ -48,5 +45,5 @@ def test_bench_sim_smoke_json_schema(tmp_path):
 @pytest.mark.slow
 def test_bench_sim_full_sweep(tmp_path):
     """Nightly: the full sweep (policy counts, workload counts,
-    blocked-vs-seed, fused-decide kernel) completes and reports sane numbers."""
+    blocked-vs-seed) completes and reports sane numbers."""
     _check_doc(_run(tmp_path), smoke=False)

@@ -2,8 +2,8 @@
 
 The script itself refuses any platform but a TPU; here each phase
 function runs on the CPU backend, where the auto rules take the XLA
-paths and the kernels the phases check (window_features, holt_winters,
-the fused episode for hpa / kpa) run in interpret mode. Each phase's own
+paths and the kernels the phases check (window_features, holt_winters)
+run in interpret mode. Each phase's own
 on-device comparisons are the assertions."""
 import importlib.util
 import json
@@ -127,12 +127,8 @@ def test_phase_episodes(trained_and_built):
     trained, _ = trained_and_built
     info = cs.phase_episodes(trained.make_classify(), n_workloads=3,
                              minutes=12)
-    assert {p["path"] for p in info.values()} == {"xla_scan"}   # CPU
-    # the policies that take the kernel on TPU were checked against the
-    # scan here, in interpret mode
-    checked = {n for n, p in info.items()
-               if p["kernel_vs_scan_max_abs_err"] is not None}
-    assert checked == {"hpa", "kpa"}
+    # every policy ran, and its lane-0 check passed
+    assert set(info) == set(cs.registry.available())
 
 
 def test_phase_fleet_dp_one_device():

@@ -12,7 +12,9 @@ import types
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
+from _hypo_compat import given, settings, st
 
 from repro.aapaset.loader import AAPAsetLoader
 from repro.dist import sharding as shd
@@ -20,7 +22,7 @@ from repro.evals import fleet, matrix
 from repro.evals import metrics as EM
 from repro.forecast import backtest
 from repro.scaling import batch, registry, scenarios
-from repro.sim.cluster import SimConfig, make_simulator
+from repro.sim.cluster import SimConfig, make_simulator, simulate
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
@@ -157,6 +159,49 @@ def test_make_simulator_w_chunk_and_donate():
         np.testing.assert_allclose(
             np.asarray(getattr(chunked, f)), np.asarray(getattr(full, f)),
             rtol=1e-5, atol=1e-5, err_msg=f)
+
+
+@settings(max_examples=4, deadline=None)
+@given(st.integers(min_value=1, max_value=4),
+       st.integers(min_value=1, max_value=3),
+       st.integers(min_value=2, max_value=7))
+def test_make_simulator_w_chunk_equals_per_lane_simulate(n_chunks, w_chunk,
+                                                          m):
+    """At any W, M and a `w_chunk` that divides W, `make_simulator`
+    equals `simulate` run on each lane alone."""
+    cfg = SimConfig(control_interval_sec=30)
+    ctrl = registry.get_controller("hpa", cfg)
+    rng = np.random.default_rng(n_chunks * 7919 + w_chunk * 31 + m)
+    rates = jnp.asarray(rng.uniform(0.0, 300.0, size=(n_chunks * w_chunk,
+                                                      m)), jnp.float32)
+    got = make_simulator(ctrl, cfg, w_chunk=w_chunk)(rates)
+    one = jax.jit(lambda r: simulate(r, ctrl, cfg))
+    for w in range(rates.shape[0]):
+        want = one(rates[w])
+        for f in want._fields:
+            np.testing.assert_allclose(
+                np.asarray(getattr(got, f)[w]), np.asarray(getattr(want, f)),
+                rtol=1e-5, atol=1e-5, err_msg=f"lane {w} {f}")
+
+
+_SIMULATORS = {
+    "make_simulator": lambda ctrl, cfg: make_simulator(ctrl, cfg),
+    "make_batch_simulator": lambda ctrl, cfg: batch.make_batch_simulator(
+        [ctrl], cfg),
+    "make_batch_simulator_w_chunk": lambda ctrl, cfg:
+        batch.make_batch_simulator([ctrl], cfg, w_chunk=2),
+}
+
+
+@pytest.mark.parametrize("build", sorted(_SIMULATORS))
+def test_simulators_compile_once(build):
+    """Two calls at one shape reuse the one compiled program."""
+    cfg = SimConfig(control_interval_sec=30)
+    run = _SIMULATORS[build](registry.get_controller("hpa", cfg), cfg)
+    rng = np.random.default_rng(0)
+    for _ in range(2):
+        run(jnp.asarray(rng.uniform(0.0, 200.0, size=(4, 6)), jnp.float32))
+    assert run._cache_size() == 1
 
 
 # ----------------------------------------------------- loader fleet feed ----

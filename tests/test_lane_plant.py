@@ -174,14 +174,16 @@ def test_uniform_lane_plant_gives_the_scalar_path(classify, mode):
     _assert_accums(got, want, mode)
 
 
-def test_single_lane_simulate_takes_the_lane_plant():
+@pytest.mark.parametrize("policy", registry.available())
+def test_single_lane_simulate_takes_the_lane_plant(policy, classify):
     """`simulate` and `simulate_reference` with a LanePlant of scalars
     equal the runs with that lane's scalar SimConfig, and
     `make_simulator` maps a [W] plant over its lanes."""
+    kw = (dict(classify=classify)
+          if registry.spec(policy).needs_classifier else {})
     rates, plant = _fleet(seed=2)
-    sims = make_simulator(registry.get_controller("predictive", CFG),
-                          CFG, decide_kernel=False, w_chunk=WC)(
-        jnp.asarray(rates), plant)
+    sims = make_simulator(registry.get_controller(policy, CFG, **kw),
+                          CFG, w_chunk=WC)(jnp.asarray(rates), plant)
     for w in (0, 5, 11):
         lane = LanePlant(*(a[w] for a in plant))
         cfg = dataclasses.replace(
@@ -189,8 +191,8 @@ def test_single_lane_simulate_takes_the_lane_plant():
             service_sec=float(lane.service_sec),
             slo_sec=float(lane.slo_sec))
         own = simulate_reference(rates[w], registry.get_controller(
-            "predictive", cfg), cfg)
-        ctrl = registry.get_controller("predictive", CFG)
+            policy, cfg, **kw), cfg)
+        ctrl = registry.get_controller(policy, CFG, **kw)
         for got in (simulate(rates[w], ctrl, CFG, plant=lane),
                     simulate_reference(rates[w], ctrl, CFG, plant=lane),
                     jax.tree.map(lambda a: a[w], sims)):
@@ -198,19 +200,6 @@ def test_single_lane_simulate_takes_the_lane_plant():
                 np.testing.assert_allclose(
                     np.asarray(getattr(got, f)), np.asarray(getattr(own, f)),
                     rtol=RTOL, atol=1e-5, err_msg=f)
-
-
-def test_decide_kernel_refuses_a_lane_plant():
-    """The fused episode kernel compiles the scalar plant: given a
-    per-lane plant it raises rather than run the scalar one."""
-    rates, plant = _fleet()
-    ctrl = registry.get_controller("hpa", CFG)
-    with pytest.raises(ValueError, match="per-lane plant"):
-        make_simulator(ctrl, CFG, decide_kernel=True)(jnp.asarray(rates),
-                                                      plant)
-    with pytest.raises(ValueError, match="per-lane plant"):
-        simulate(rates[0], ctrl, CFG, decide_kernel=True,
-                 plant=LanePlant(*(a[0] for a in plant)))
 
 
 # ------------------------------------------- stages of the plant reads ----

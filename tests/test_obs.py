@@ -155,6 +155,17 @@ def test_fleet_trace_lanes_rides_chunk_scan():
         fleet.run_fleet(sp1, stream=True)
 
 
+def test_telemetry_w_chunk_error_names_fleet_front_door():
+    """The telemetry+w_chunk rejection must point at the actual recourse:
+    FleetSpec(..., trace_lanes=K) via repro.evals.fleet."""
+    cfg = SimConfig()
+    with pytest.raises(ValueError) as ei:
+        batch.make_batch_simulator([_ctrl("hpa", cfg)], cfg, telemetry=True,
+                                   w_chunk=4)
+    msg = str(ei.value)
+    assert "trace_lanes" in msg and "evals.fleet" in msg
+
+
 # ------------------------------------------------------------ attribution
 def test_blame_counts_sum_to_pooled_violations():
     """The acceptance pin: per-cause blame totals over every traced lane

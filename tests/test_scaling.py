@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.core import gbdt
 from repro.scaling import api, batch, registry, scenarios
 from repro.sim import metrics as M
 from repro.sim.cluster import SimConfig, make_simulator, simulate
@@ -17,6 +18,24 @@ CFG = SimConfig()
 def _rates(shape, lam=1200, seed=0):
     return jnp.asarray(
         np.random.default_rng(seed).poisson(lam, shape).astype(np.float32))
+
+
+def _tiny_gbdt():
+    """A real (tiny) trained GBDT, so the classifier policies run actual
+    node-table inference, not the constant fallback classifier."""
+    rng = np.random.default_rng(11)
+    X = rng.normal(size=(96, 38)).astype(np.float32)
+    y = rng.integers(0, 4, 96).astype(np.int32)
+    return gbdt.fit(X, y, gbdt.GBDTConfig(n_rounds=4, depth=3))
+
+
+def _gbdt_classify(params):
+    def classify(feats):
+        logits = gbdt.predict_logits(params, feats[None, :])[0]
+        p = jax.nn.softmax(logits)
+        return jnp.argmax(p).astype(jnp.int32), jnp.max(p).astype(
+            jnp.float32)
+    return classify
 
 
 # ------------------------------------------------------------- registry ----
@@ -71,6 +90,31 @@ def test_batch_simulate_matches_per_policy_simulators():
                 np.asarray(getattr(out, field)[i]),
                 np.asarray(getattr(single, field)), rtol=1e-5, atol=1e-5,
                 err_msg=f"{ctrl.name}.{field}")
+
+
+# ci=30 keeps the unrolled-tick program small (the 29-tick remainder
+# goes through lax.scan), so each policy compiles in seconds.
+_CI30 = SimConfig(control_interval_sec=30)
+
+
+@pytest.mark.parametrize("policy", registry.available())
+def test_batch_core_matches_single_lane_core(policy):
+    """The batch core (`make_batch_simulator`) equals the single-lane
+    core (`make_simulator`) on every MinuteOut field, for every registry
+    policy. Classifier policies run a real tiny GBDT with stride_min=2,
+    so reclassification fires mid-episode."""
+    rates = jnp.asarray(np.random.default_rng(5).uniform(
+        0.0, 200.0, size=(5, 6)), jnp.float32)
+    kw = {}
+    if registry.spec(policy).needs_classifier:
+        kw = dict(classify=_gbdt_classify(_tiny_gbdt()), stride_min=2)
+    ctrl = registry.get_controller(policy, _CI30, **kw)
+    got = batch.make_batch_simulator([ctrl], _CI30)(rates)
+    want = make_simulator(ctrl, _CI30)(rates)
+    for f in want._fields:
+        np.testing.assert_allclose(
+            np.asarray(getattr(got, f)[0]), np.asarray(getattr(want, f)),
+            rtol=1e-5, atol=1e-5, err_msg=f"{policy}.{f}")
 
 
 def test_grid_simulator_matches_individual_factories():

@@ -3,14 +3,13 @@
 No chip is attached: `jax.experimental.topologies` describes a v5e and
 the TPU compiler (Mosaic) compiles each kernel for it at the shapes
 ``chip_smoke.py`` runs, so a kernel the chip would refuse fails here.
-Each test asserts the compiled program holds the kernel
-(`tpu_custom_call`). Covered: `window_features` (dataset build),
-`holt_winters` (every Holt-Winters `smooth` on TPU), and `episode_block`
-for every policy the `decide_kernel` auto rule sends to it
-(`Controller.tpu_kernel`). Besides, the fleet runner's AAPA
-reclassification is compiled for the CPU and for the v5e and checked to
-hold no loop and no gather, and its forecast stage to hold no gather and
-no scatter.
+Each kernel's test asserts the compiled program holds the kernel
+(`tpu_custom_call`). Covered: `window_features` (dataset build) and
+`holt_winters` (every Holt-Winters `smooth` on TPU). The lane scan
+(`make_simulator`) compiles for the v5e with no kernel in it. Besides,
+the fleet runner's AAPA reclassification is compiled for the CPU and for
+the v5e and checked to hold no loop and no gather, and its forecast
+stage to hold no gather and no scatter.
 
 The topology is described inside a module fixture — never at import —
 so under pytest-xdist only the worker that runs this file loads the TPU
@@ -26,10 +25,7 @@ import pytest
 
 from repro.kernels import ops
 from repro.scaling import registry
-from repro.sim.cluster import SimConfig
-
-#: the policies whose decide lowers inside the episode kernel on TPU
-KERNEL_POLICIES = ("hpa", "kpa")
+from repro.sim.cluster import SimConfig, make_simulator
 
 
 @pytest.fixture(scope="module")
@@ -82,25 +78,17 @@ def test_holt_winters_compiles(one_chip, no_compile_cache):
     assert "tpu_custom_call" in txt
 
 
-@pytest.mark.parametrize("policy", KERNEL_POLICIES)
+@pytest.mark.parametrize("policy", ("hpa", "kpa"))
 @pytest.mark.parametrize("lanes", (1, 1000))
-def test_episode_block_compiles(policy, lanes, one_chip, no_compile_cache):
+def test_lane_scan_compiles(policy, lanes, one_chip, no_compile_cache):
+    """`make_simulator` at the episode length ``chip_smoke.py`` runs:
+    one XLA scan on every platform, with no Pallas kernel in it."""
     cfg = SimConfig()
-    ctrl = registry.get_controller(policy, cfg)
-    txt = _compile_text(
-        lambda r: ops.episode_block(r, ctrl, cfg, interpret=False),
-        (lanes, 240), sharding=one_chip)
-    assert "tpu_custom_call" in txt
-
-
-def test_kernel_policies_are_the_auto_rule_set():
-    """The parametrization above covers exactly the policies that
-    declare `tpu_kernel` — a policy added to the auto rule must be
-    compiled here too."""
-    cfg = SimConfig()
-    declared = tuple(n for n in registry.available()
-                     if registry.get_controller(n, cfg).tpu_kernel)
-    assert declared == KERNEL_POLICIES
+    run = make_simulator(registry.get_controller(policy, cfg), cfg)
+    rates = jax.ShapeDtypeStruct((lanes, 240), jnp.float32,
+                                 sharding=one_chip)
+    txt = run.lower(rates).compile().as_text()
+    assert "tpu_custom_call" not in txt
 
 
 @pytest.mark.parametrize("platform", ["cpu", "v5e"])
